@@ -11,6 +11,10 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="run a single module")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         chaos_soak,
         encoder_serving,

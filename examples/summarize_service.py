@@ -35,6 +35,7 @@ import time
 from repro.core import SolveConfig
 from repro.data.synthetic import synthetic_document
 from repro.farm import DRAIN_POLICIES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (
     AdmissionConfig,
     EngineOverloadedError,
@@ -190,6 +191,7 @@ def main():
                     help="CalibrationProfile JSON for --route (default: "
                          "built-in hardware-constant profile)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     admission = None
     if args.max_queue_depth > 0 or args.deadline > 0:

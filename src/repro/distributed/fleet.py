@@ -65,18 +65,8 @@ def make_fleet_solver(mesh: Mesh, *, steps: int = 500, dt: float = 0.35,
 
     in_specs = (P(dp, None), P(dp, None, None), P(dp, "model", None))
     out_specs = (P(dp, None), P(dp))
-    fn = _shard_map(local_anneal, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs)
-    return fn
-
-
-def _shard_map(*args, **kwargs):
-    """jax.shard_map moved out of jax.experimental in newer releases; take
-    whichever this jax provides."""
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-    return shard_map(*args, **kwargs)
+    return jax.shard_map(local_anneal, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def fleet_solve(mesh: Mesh, h: Array, j: Array, key: Array, *,

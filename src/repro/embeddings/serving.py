@@ -273,9 +273,8 @@ class EncoderStage:
                                          self.sim_now())
             fut._finish(jnp.zeros((0, self.cfg.d_model), jnp.float32), None)
             return fut
-        n_tok = min(1 + sum(len(t.encode("utf-8")) + 1 for t in texts),
-                    self.max_len)
-        length = min(_bucket(n_tok, MIN_LEN_BUCKET), self.max_len)
+        n_tok = self._n_tokens(texts)
+        length, _ = self.job_shape(texts)
         tokens, segs = self.tok.encode_sentences(texts, length)
         job = _EncodeJob(job_id, len(texts), tokens, segs, n_tok, fut, tag,
                          workload)
@@ -289,6 +288,17 @@ class EncoderStage:
                 self._driver.start()
             self._cond.notify_all()
         return fut
+
+    def _n_tokens(self, texts: Sequence[str]) -> int:
+        return min(1 + sum(len(t.encode("utf-8")) + 1 for t in texts),
+                   self.max_len)
+
+    def job_shape(self, texts: Sequence[str]) -> Tuple[int, int]:
+        """(length bucket, segment bucket) a job of ``texts`` encodes at:
+        the shape :meth:`prewarm` must cover for that job."""
+        length = min(_bucket(self._n_tokens(texts), MIN_LEN_BUCKET),
+                     self.max_len)
+        return length, _bucket(len(texts), SEG_BUCKET)
 
     def encode(self, texts: Sequence[str]) -> jnp.ndarray:
         """Synchronous face: submit + wait.  Makes a stage usable anywhere

@@ -69,6 +69,9 @@ Array = jax.Array
 
 LANE = 128  # f32 lane tile on TPU
 DEFAULT_REPLICA_BLOCK = 256
+# Energy dots run at full f32 precision on the MXU so integer energies stay
+# exact; the anneal's dynamics matmul keeps the backend default.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _anneal_loop(j, h, phi, *, steps: int, dt: float, ks_max: float):
@@ -111,27 +114,45 @@ def _slot_energies(s, j_orig, h_orig, mask, reads, rep_base):
     h.s + s^T J s within each block-diagonal slot, so one matmul with the
     0/1 lane->slot ``mask`` yields every slot's energy.  All partial sums
     are integers for chip-range instances, hence f32-exact and bit-identical
-    to the standalone ising_energy kernel / einsum oracle.
+    to the standalone ising_energy kernel / einsum oracle.  Both dots ask
+    for full f32 precision: a one-pass bf16 MXU product would round lane
+    energies above 256.
     """
-    sj = jnp.dot(s, j_orig, preferred_element_type=jnp.float32)  # MXU
+    sj = jnp.dot(s, j_orig, preferred_element_type=jnp.float32, precision=_EXACT)
     e_lanes = s * sj + h_orig * s  # (BR, N)
-    e_slots = jnp.dot(e_lanes, mask, preferred_element_type=jnp.float32)  # (BR, S)
-    local = jax.lax.broadcasted_iota(jnp.float32, e_slots.shape, 0)
+    e_slots = jnp.dot(e_lanes, mask, preferred_element_type=jnp.float32,
+                      precision=_EXACT)  # (BR, S)
+    local = _row_index(e_slots.shape)
     e_slots = jnp.where(local + rep_base < reads, e_slots, jnp.inf)
     return e_slots, local
 
 
+def _row_index(shape):
+    """f32 row index of a 2-D block.  Mosaic builds iotas in int32 only; the
+    values are small integers, so the cast is exact."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0).astype(jnp.float32)
+
+
 def _block_best(s, e_slots, local):
-    """(min energy, first-argmin spin row) per slot within one replica block."""
-    br, ns = e_slots.shape
-    blk_min = jnp.min(e_slots, axis=0)  # (S,)
-    hit = e_slots == blk_min[None, :]
-    first = jnp.min(jnp.where(hit, local, jnp.float32(br)), axis=0)  # (S,)
-    onehot = (
-        jax.lax.broadcasted_iota(jnp.float32, (ns, br), 1) == first[:, None]
-    ).astype(jnp.float32)
-    rows = jnp.dot(onehot, s, preferred_element_type=jnp.float32)  # (S, N)
-    return blk_min, rows
+    """(min energy (S, 1), first-argmin spin rows (S, N)) per slot within one
+    replica block.
+
+    Every intermediate stays 2-D: Mosaic cannot lay out the 1-D per-slot
+    vectors a plain ``min(axis=0)`` would give.  The (BR, S) one-hot of each
+    slot's first minimum is contracted over BR, which picks exactly one
+    {-1, +1} row per slot (an exact sum).
+    """
+    br = e_slots.shape[0]
+    blk_min = jnp.min(e_slots, axis=0, keepdims=True)  # (1, S)
+    hit = e_slots == blk_min
+    first = jnp.min(
+        jnp.where(hit, local, jnp.float32(br)), axis=0, keepdims=True
+    )  # (1, S)
+    onehot = (local == first).astype(jnp.float32)  # (BR, S)
+    rows = jax.lax.dot_general(
+        onehot, s, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )  # (S, N)
+    return blk_min.T, rows
 
 
 def _carry_best(i, blk_min, rows, e_ref, s_ref):
@@ -139,22 +160,21 @@ def _carry_best(i, blk_min, rows, e_ref, s_ref):
 
     The output BlockSpecs map every replica-block index to the same block, so
     its VMEM contents persist across the innermost grid dimension -- the
-    standard Pallas accumulation-by-revisiting pattern.
+    standard Pallas accumulation-by-revisiting pattern.  ``blk_min`` is
+    (S, 1); the running best is kept broadcast across the (S, LANE) block.
     """
 
     @pl.when(i == 0)
     def _():
-        e_ref[...] = jnp.broadcast_to(blk_min[:, None], e_ref.shape)
+        e_ref[...] = jnp.broadcast_to(blk_min, e_ref.shape)
         s_ref[...] = rows
 
     @pl.when(i != 0)
     def _():
-        prev = e_ref[..., 0]  # (S,)
+        prev = e_ref[:, :1]  # (S, 1)
         better = blk_min < prev  # strict: earlier replica block wins ties
-        e_ref[...] = jnp.broadcast_to(
-            jnp.where(better, blk_min, prev)[:, None], e_ref.shape
-        )
-        s_ref[...] = jnp.where(better[:, None], rows, s_ref[...])
+        e_ref[...] = jnp.broadcast_to(jnp.where(better, blk_min, prev), e_ref.shape)
+        s_ref[...] = jnp.where(better, rows, s_ref[...])
 
 
 def _cobi_kernel(j_ref, h_ref, phi_ref, out_ref, *, steps: int, dt: float, ks_max: float):
@@ -221,7 +241,7 @@ def _cobi_readout_kernel(
         j_ref[...], h_ref[...], phi_ref[...], steps=steps, dt=dt, ks_max=ks_max
     )
     s = _sign_spins(phi)
-    sj = jnp.dot(s, ju_ref[...], preferred_element_type=jnp.float32)
+    sj = jnp.dot(s, ju_ref[...], preferred_element_type=jnp.float32, precision=_EXACT)
     e = jnp.sum(s * sj, axis=-1, keepdims=True) + jnp.sum(
         s * hu_ref[...], axis=-1, keepdims=True
     )

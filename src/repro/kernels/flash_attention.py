@@ -9,6 +9,10 @@ innermost (sequential) axis, accumulating into VMEM scratch:
 Each (b, h, qb) output tile is written once, on the last kv step.  GQA maps
 query head h to kv head h // (H // KV) purely via the BlockSpec index_map --
 no repeated K/V materialization in HBM.
+
+The kernel works on head-major (B, H, S, D) arrays so every block's last two
+dims are a (rows, D) tile: Mosaic refuses a block that puts a unit head axis
+among the last two dims of the caller's (B, S, H, D) layout.
 """
 
 from __future__ import annotations
@@ -39,9 +43,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :]  # (BQ, D)
-    k = k_ref[0, :, 0, :]  # (BK, D)
-    v = v_ref[0, :, 0, :]  # (BK, D)
+    q = q_ref[0, 0]  # (BQ, D)
+    k = k_ref[0, 0]  # (BK, D)
+    v = v_ref[0, 0]  # (BK, D)
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (BQ, BK)
 
@@ -69,7 +73,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ki == nk - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -97,20 +101,22 @@ def flash_attention(
         _fa_kernel, scale=scale, causal=causal, window=window,
         bq=bq, bk=bk, sq=sq, skv=skv,
     )
-    return pl.pallas_call(
+    heads_major = lambda x: jnp.swapaxes(x, 1, 2)  # (B, S, H, D) <-> (B, H, S, D)
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, d), lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b_, h_, qi, ki, rep=rep: (b_, ki, h_ // rep, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda b_, h_, qi, ki, rep=rep: (b_, ki, h_ // rep, 0)),
+            pl.BlockSpec((1, 1, bq, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, qi, ki, rep=rep: (b_, h_ // rep, ki, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, qi, ki, rep=rep: (b_, h_ // rep, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, d), lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, LANE), jnp.float32),  # m
             pltpu.VMEM((bq, LANE), jnp.float32),  # l
             pltpu.VMEM((bq, d), jnp.float32),  # acc
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(heads_major(q), heads_major(k), heads_major(v))
+    return heads_major(out)

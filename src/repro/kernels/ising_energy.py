@@ -22,7 +22,8 @@ DEFAULT_REPLICA_BLOCK = 512
 def _energy_core(s, h, j):
     """Shared bilinear form: identical op sequence in the single and batched
     kernels so packed-instance scores match per-instance scores exactly."""
-    sj = jnp.dot(s, j, preferred_element_type=jnp.float32)  # MXU
+    sj = jnp.dot(s, j, preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)  # MXU, full f32
     return jnp.sum(s * sj, axis=-1, keepdims=True) + jnp.sum(s * h, axis=-1, keepdims=True)
 
 

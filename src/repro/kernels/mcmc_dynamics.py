@@ -37,8 +37,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.cobi_dynamics import LANE, _block_best, _carry_best
-from repro.kernels.ref import mcmc_u01
+from repro.kernels.cobi_dynamics import (
+    _EXACT, LANE, _block_best, _carry_best, _row_index,
+)
+from repro.kernels.ref import mcmc_temperature, mcmc_u01
 
 Array = jax.Array
 
@@ -61,14 +63,14 @@ def _mcmc_loop(
     n = s0.shape[-1]
     assert n % chunk == 0, (n, chunk)
     n_chunks = n // chunk
-    lanes = jax.lax.broadcasted_iota(jnp.float32, (1, n), 1)
-    f0 = jnp.dot(s0, j, preferred_element_type=jnp.float32)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1).astype(jnp.float32)
+    f0 = jnp.dot(s0, j, preferred_element_type=jnp.float32, precision=_EXACT)
     e0 = jnp.sum(s0 * h + s0 * f0, axis=1, keepdims=True)
-    ratio = t_lo / t_hi
+    log_ratio = jnp.log(t_lo / t_hi)
     denom = jnp.float32(max(sweeps - 1, 1))
 
     def sweep_body(ts, carry):
-        temp = t_hi * ratio ** (ts.astype(jnp.float32) / denom)
+        temp = mcmc_temperature(t_hi, log_ratio, ts, denom)
         ts_u = ts.astype(jnp.uint32)
 
         def t_body(t, carry):
@@ -84,7 +86,7 @@ def _mcmc_loop(
             s_k = jnp.sum(s * onehot, axis=1, keepdims=True)
             f_k = jnp.sum(f * onehot, axis=1, keepdims=True)
             h_k = jnp.sum(h * onehot, axis=1, keepdims=True)
-            j_k = jnp.dot(onehot, j, preferred_element_type=jnp.float32)
+            j_k = jnp.dot(onehot, j, preferred_element_type=jnp.float32, precision=_EXACT)
             de = -2.0 * s_k * (h_k + 2.0 * f_k)
             accept = u_acc < jnp.exp(
                 jnp.minimum(-de / jnp.maximum(temp, 1e-9), 0.0)
@@ -163,7 +165,7 @@ def _mcmc_fused_best_kernel(
         j_ref[0], h_ref[0], s0_ref[0], seed_pick, seed_acc, rep,
         t_hi, t_lo, n_live, sweeps=sweeps, chunk=chunk, mode=mode,
     )
-    local = jax.lax.broadcasted_iota(jnp.float32, (br, 1), 0)
+    local = _row_index((br, 1))
     rep_base = (i * br).astype(jnp.float32)
     e_slots = jnp.where(local + rep_base < reads, best_e, jnp.inf)
     blk_min, rows = _block_best(best_s, e_slots, local)

@@ -12,6 +12,9 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
+# Full f32 dots where the kernels ask for them (see kernels/cobi_dynamics.py).
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 # ---------------------------------------------------------------------------
 # COBI coupled-oscillator dynamics
@@ -147,7 +150,15 @@ def mcmc_u01(seed: Array, rep: Array, sweep: Array, pos: Array) -> Array:
         + jnp.asarray(pos, jnp.uint32) * jnp.uint32(MCMC_CTR_POS)
     )
     bits = mcmc_mix32(x) >> jnp.uint32(8)
-    return bits.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # Below 2**24, so the int32 hop is exact; Mosaic has no uint32 -> f32 cast.
+    return bits.astype(jnp.int32).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+
+
+def mcmc_temperature(t_hi: Array, log_ratio: Array, ts: Array, denom: Array) -> Array:
+    """Sweep ``ts`` of the geometric ladder t_hi * (t_lo/t_hi)^(ts/denom),
+    written as exp(log_ratio * x) because Mosaic cannot lower ``powf``.
+    Shared by the kernel and :func:`ref_mcmc_sweep` so both walk one ladder."""
+    return t_hi * jnp.exp(log_ratio * (ts.astype(jnp.float32) / denom))
 
 
 def mcmc_seeds(key: Array) -> Array:
@@ -213,13 +224,13 @@ def ref_mcmc_sweep(
     rep = jnp.arange(replicas, dtype=jnp.uint32)[:, None]
     lanes = jnp.arange(n, dtype=jnp.float32)[None, :]
     s0 = mcmc_init_spins(seeds[0], replicas, n)
-    f0 = jnp.dot(s0, j, preferred_element_type=jnp.float32)
+    f0 = jnp.dot(s0, j, preferred_element_type=jnp.float32, precision=_EXACT)
     e0 = jnp.sum(s0 * hrow + s0 * f0, axis=1, keepdims=True)
-    ratio = t_lo / t_hi
+    log_ratio = jnp.log(t_lo / t_hi)
     denom = jnp.float32(max(sweeps - 1, 1))
 
     def sweep_body(ts, carry):
-        temp = t_hi * ratio ** (ts.astype(jnp.float32) / denom)
+        temp = mcmc_temperature(t_hi, log_ratio, ts, denom)
         ts_u = ts.astype(jnp.uint32)
 
         def t_body(t, carry):
@@ -235,7 +246,7 @@ def ref_mcmc_sweep(
             s_k = jnp.sum(s * onehot, axis=1, keepdims=True)
             f_k = jnp.sum(f * onehot, axis=1, keepdims=True)
             h_k = jnp.sum(hrow * onehot, axis=1, keepdims=True)
-            j_k = jnp.dot(onehot, j, preferred_element_type=jnp.float32)
+            j_k = jnp.dot(onehot, j, preferred_element_type=jnp.float32, precision=_EXACT)
             de = -2.0 * s_k * (h_k + 2.0 * f_k)
             accept = u_acc < jnp.exp(
                 jnp.minimum(-de / jnp.maximum(temp, 1e-9), 0.0)

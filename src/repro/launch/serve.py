@@ -27,6 +27,7 @@ import argparse
 
 from repro.core import SolveConfig
 from repro.data.synthetic import synthetic_document
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import AdmissionConfig, EngineOverloadedError, SummarizationEngine
 from repro.workloads import build_request
 
@@ -83,6 +84,7 @@ def main():
                     help="disable span/event tracing (the registry stays "
                          "live; responses are bit-identical either way)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     admission = (AdmissionConfig(max_queue_depth=args.max_queue_depth)
                  if args.max_queue_depth > 0 else None)

@@ -31,7 +31,7 @@ Array = jax.Array
 def make_train_step(cfg: ModelConfig, opt_cfg: opt.OptConfig, mesh=None):
     """Microbatched (grad-accumulation) train step: loss -> AdamW update."""
 
-    def train_step(params, opt_state, batch):
+    def body(params, opt_state, batch):
       with activation_mesh(mesh):
         mb = cfg.microbatch
 
@@ -57,6 +57,18 @@ def make_train_step(cfg: ModelConfig, opt_cfg: opt.OptConfig, mesh=None):
         params, opt_state, metrics = opt.apply_updates(params, grads, opt_state, opt_cfg)
         metrics["loss"] = loss
         return params, opt_state, metrics
+
+    def train_step(params, opt_state, batch):
+        if not jax.sharding.get_abstract_mesh().explicit_axes:
+            return body(params, opt_state, batch)
+        # The step relies on GSPMD propagation between its sharding
+        # constraints; under a mesh with explicit axes (the default of
+        # jax.make_mesh) run it in auto mode, handing params and optimizer
+        # state back sharded as they came in.
+        spec = lambda x: jax.typeof(x).sharding.spec
+        out_sharding = (jax.tree.map(spec, params), jax.tree.map(spec, opt_state), P())
+        return jax.sharding.auto_axes(body, out_sharding=out_sharding)(
+            params, opt_state, batch)
 
     return train_step
 
