@@ -29,6 +29,7 @@ from repro.core.formulation import (
     original_ising,
 )
 from repro.core.rounding import COBI_RANGE, quantize_ising, quantize_ising_many
+from repro.obs import NULL_SPAN, Tracer
 from repro.solvers import base as solver_base
 from repro.solvers import brute as brute_solver
 from repro.solvers import random_baseline
@@ -333,6 +334,18 @@ def _solve_decomposed(problem: EsProblem, key: Array, cfg: SolveConfig) -> Solve
 # ---------------------------------------------------------------------------
 
 
+_NO_TRACER = Tracer(enabled=False)
+
+
+def _driver_span(tracer: Tracer, name: str, tag: Optional[int]):
+    """Host-work span on the serving driver's track, parented to the
+    request's root span (``NULL_SPAN`` with tracing off)."""
+    if not tracer.enabled:
+        return NULL_SPAN
+    return tracer.span(name, trace_id=tag, parent=tracer.root_id(tag),
+                       track="driver")
+
+
 @dataclasses.dataclass
 class _Round:
     """One submission round plus the recipe to resubmit any iteration.
@@ -341,10 +354,13 @@ class _Round:
     (quantized instance, solve key) -- to the original backend or to a
     failover one -- so a retried job is bit-identical to the original
     wherever it lands (results depend only on instance and key).
+    ``tracer`` (the backend's) and ``tag`` carry to the round's reduce.
     """
 
     futures: list
     resubmit: Callable
+    tracer: Tracer = _NO_TRACER
+    tag: Optional[int] = None
 
 
 def _submit_iterations(
@@ -358,45 +374,45 @@ def _submit_iterations(
     replica spins/energies on device and each future resolves to just the
     winner (bit-identical to all-reads + host argmin on integer instances;
     host backends apply the same first-argmin reduction in the worker).
+    The whole formulation runs inside a ``solve.formulate`` span when the
+    backend's tracer is on.
     """
-    ising_fp = _build_ising(problem, cfg)
-    check = cfg.int_range is not None or cfg.bits is not None
-    keypairs = _iteration_keys(key, cfg.iterations)
-    if check:
-        # Same per-iteration keys as the sequential path, one fused launch.
-        quantized = quantize_ising_many(
-            ising_fp, jnp.stack([kq for kq, _ in keypairs]), cfg.rounding,
-            int_range=cfg.int_range or COBI_RANGE, bits=cfg.bits,
-        )
-        instances = [q.ising for q in quantized]
-    else:
-        instances = [ising_fp] * cfg.iterations
+    obs = getattr(backend, "obs", None)
+    tracer = obs.tracer if obs is not None else _NO_TRACER
+    with _driver_span(tracer, "solve.formulate", tag):
+        ising_fp = _build_ising(problem, cfg)
+        check = cfg.int_range is not None or cfg.bits is not None
+        keypairs = _iteration_keys(key, cfg.iterations)
+        if check:
+            # Same per-iteration keys as the sequential path, one fused
+            # launch.
+            quantized = quantize_ising_many(
+                ising_fp, jnp.stack([kq for kq, _ in keypairs]), cfg.rounding,
+                int_range=cfg.int_range or COBI_RANGE, bits=cfg.bits,
+            )
+            instances = [q.ising for q in quantized]
+        else:
+            instances = [ising_fp] * cfg.iterations
 
-    def submit_one(i: int, be=None, dl=deadline):
-        # Failover resubmits drop the deadline: it lives on the PRIMARY
-        # backend's clock and recovery already budgeted the move against it.
-        return (be or backend).submit(
-            instances[i], keypairs[i][1], reads=cfg.reads, steps=cfg.steps,
-            priority=priority, deadline=dl if be is None else None,
-            check=check, reduce="best", tag=tag,
-        )
+        def submit_one(i: int, be=None, dl=deadline):
+            # Failover resubmits drop the deadline: it lives on the PRIMARY
+            # backend's clock and recovery already budgeted the move
+            # against it.
+            return (be or backend).submit(
+                instances[i], keypairs[i][1], reads=cfg.reads,
+                steps=cfg.steps, priority=priority,
+                deadline=dl if be is None else None,
+                check=check, reduce="best", tag=tag,
+            )
 
-    return _Round([submit_one(i) for i in range(cfg.iterations)], submit_one)
+        futures = [submit_one(i) for i in range(cfg.iterations)]
+    return _Round(futures, submit_one, tracer, tag)
 
 
-def _reduce_iterations(problem: EsProblem, cfg: SolveConfig, futures):
-    """Consume one instance's iteration futures -> best-of + accounting.
-
-    Each future is released after its result AND receipt are consumed, so a
-    long-lived backend's completed-job buffers stay bounded under continuous
-    serving without a batch-scoped ``clear_completed`` sweep.
-    """
+def _best_of(problem: EsProblem, cfg: SolveConfig, results):
+    """Repair and score each iteration's winning read; best-of in order."""
     best_x, best_obj, curve = None, -np.inf, []
-    acct = _Acct()
-    for fut in futures:
-        result = fut.result()
-        acct.add(fut.receipt())
-        fut.release()
+    for result in results:
         x = _best_selection(result)
         if cfg.repair:
             x = repair_selection(problem, x)
@@ -404,6 +420,35 @@ def _reduce_iterations(problem: EsProblem, cfg: SolveConfig, futures):
         if obj > best_obj:
             best_obj, best_x = obj, x
         curve.append(best_obj)
+    return best_x, best_obj, curve
+
+
+def _reduce_iterations(problem: EsProblem, cfg: SolveConfig, rnd: _Round):
+    """Consume one round's iteration futures -> best-of + accounting, inside
+    a ``solve.reduce`` span (its ``waited_s``: seconds blocked on futures
+    that were not yet done, as on a self-draining backend).
+
+    Each future is released after its result AND receipt are consumed, so a
+    long-lived backend's completed-job buffers stay bounded under continuous
+    serving without a batch-scoped ``clear_completed`` sweep.
+    """
+    tracer = rnd.tracer
+    with _driver_span(tracer, "solve.reduce", rnd.tag) as sp:
+        acct = _Acct()
+        results = []
+        waited = 0.0
+        for fut in rnd.futures:
+            if sp and not fut.done():
+                t = tracer.now()
+                results.append(fut.result())
+                waited += tracer.now() - t
+            else:
+                results.append(fut.result())
+            acct.add(fut.receipt())
+            fut.release()
+        best_x, best_obj, curve = _best_of(problem, cfg, results)
+        if sp:
+            sp.set(waited_s=waited)
     return best_x, best_obj, curve, acct
 
 
@@ -465,15 +510,8 @@ def _reduce_with_recovery(problem: EsProblem, cfg: SolveConfig, rnd: _Round,
                 fut.cancel()
                 fut.add_done_callback(lambda f: f.release())
         raise
-    best_x, best_obj, curve = None, -np.inf, []
-    for result in results:
-        x = _best_selection(result)
-        if cfg.repair:
-            x = repair_selection(problem, x)
-        obj = _objective_np(problem, x)
-        if obj > best_obj:
-            best_obj, best_x = obj, x
-        curve.append(best_obj)
+    with _driver_span(rnd.tracer, "solve.reduce", rnd.tag):
+        best_x, best_obj, curve = _best_of(problem, cfg, results)
     return best_x, best_obj, curve, acct
 
 
@@ -487,7 +525,7 @@ def _iter_iterations(
                              deadline, tag)
     yield rnd.futures
     if recovery is None:
-        return _reduce_iterations(problem, cfg, rnd.futures)
+        return _reduce_iterations(problem, cfg, rnd)
     return (yield from _reduce_with_recovery(problem, cfg, rnd, recovery))
 
 
@@ -702,7 +740,7 @@ def _iter_decomposed(
         if not all(f.done() for f in rnd.futures):
             yield rnd.futures
         if recovery is None:
-            sel, _, _, sub_acct = _reduce_iterations(sub, sub_cfg, rnd.futures)
+            sel, _, _, sub_acct = _reduce_iterations(sub, sub_cfg, rnd)
         else:
             sel, _, _, sub_acct = yield from _reduce_with_recovery(
                 sub, sub_cfg, rnd, recovery)

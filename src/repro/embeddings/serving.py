@@ -52,7 +52,7 @@ import numpy as np
 
 from repro.data.tokenizer import ByteTokenizer
 from repro.models import embed_sentences
-from repro.obs import Observability
+from repro.obs import NULL_SPAN, Observability
 from repro.solvers.base import AwaitableFuture
 
 # Power-of-two padding bases (the farm's BATCH_BUCKET/REPLICA_BUCKET idiom):
@@ -260,7 +260,9 @@ class EncoderStage:
         The job's length bucket is a pure function of its own texts, so
         its embeddings never depend on what else is queued.  ``workload``
         labels the job's sec/token observation (see
-        :meth:`estimate_seconds`)."""
+        :meth:`estimate_seconds`).  Tokenizing and building the job run in
+        an ``encoder.tokenize`` span on the caller's (the engine driver's)
+        track."""
         texts = list(texts)
         with self._lock:
             if self._closed:
@@ -273,11 +275,18 @@ class EncoderStage:
                                          self.sim_now())
             fut._finish(jnp.zeros((0, self.cfg.d_model), jnp.float32), None)
             return fut
-        n_tok = self._n_tokens(texts)
-        length, _ = self.job_shape(texts)
-        tokens, segs = self.tok.encode_sentences(texts, length)
-        job = _EncodeJob(job_id, len(texts), tokens, segs, n_tok, fut, tag,
-                         workload)
+        tracer = self.obs.tracer
+        sp = NULL_SPAN
+        if tracer.enabled:
+            sp = tracer.span("encoder.tokenize", trace_id=tag,
+                             parent=tracer.root_id(tag), track="driver",
+                             n_texts=len(texts))
+        with sp:
+            n_tok = self._n_tokens(texts)
+            length, _ = self.job_shape(texts)
+            tokens, segs = self.tok.encode_sentences(texts, length)
+            job = _EncodeJob(job_id, len(texts), tokens, segs, n_tok, fut,
+                             tag, workload)
         with self._cond:
             self._queue.append(job)
             if self._driver is None:
@@ -533,18 +542,51 @@ class EncoderStage:
             self._run_group(length, groups[length])
 
     def _run_group(self, length: int, jobs: List[_EncodeJob]) -> None:
-        b_pad = _bucket(len(jobs), BATCH_BUCKET)
-        g_pad = _bucket(max(j.n_items for j in jobs), SEG_BUCKET)
-        tokens = np.zeros((b_pad, length), np.int32)
-        segs = np.full((b_pad, length), -1, np.int32)
-        for i, job in enumerate(jobs):
-            tokens[i] = job.tokens
-            segs[i] = job.segs
-        t_start = time.monotonic()
-        out = _embed_batch(self.cfg, self.params, jnp.asarray(tokens),
-                           jnp.asarray(segs), int(g_pad))
-        out.block_until_ready()
-        t_end = time.monotonic()
+        """One launch.  With tracing on it runs in an ``encoder.batch`` span
+        holding ``encoder.pack`` (padding and the host-to-device copies),
+        ``encoder.launch`` (the jitted embed until its result is ready; the
+        jobs' ``encode.job`` spans take its readings) and ``encoder.readout``
+        (meters, per-job rows, receipts, futures)."""
+        tracer = self.obs.tracer
+        traced = tracer.enabled
+        batch = NULL_SPAN
+        if traced:
+            batch = tracer.span("encoder.batch", track="encoder",
+                                jobs=len(jobs), padded_len=length)
+        with batch:
+            tp0 = tracer.now() if traced else 0.0
+            b_pad = _bucket(len(jobs), BATCH_BUCKET)
+            g_pad = _bucket(max(j.n_items for j in jobs), SEG_BUCKET)
+            tokens = np.zeros((b_pad, length), np.int32)
+            segs = np.full((b_pad, length), -1, np.int32)
+            for i, job in enumerate(jobs):
+                tokens[i] = job.tokens
+                segs[i] = job.segs
+            tokens_d, segs_d = jnp.asarray(tokens), jnp.asarray(segs)
+            tl0 = tracer.now() if traced else 0.0
+            t_start = time.monotonic()
+            out = _embed_batch(self.cfg, self.params, tokens_d, segs_d,
+                               int(g_pad))
+            out.block_until_ready()
+            t_end = time.monotonic()
+            readout = NULL_SPAN
+            if traced:
+                tl1 = tracer.now()
+                tracer.emit_span("encoder.pack", parent=batch.span_id,
+                                 track="encoder", t0=tp0, t1=tl0,
+                                 batch_pad=b_pad)
+                tracer.emit_span("encoder.launch", parent=batch.span_id,
+                                 track="encoder", t0=tl0, t1=tl1)
+                readout = batch.child("encoder.readout")
+            with readout:
+                self._read_out(length, jobs, out, t_start, t_end,
+                               (tl0, tl1) if traced else None)
+
+    def _read_out(self, length: int, jobs: List[_EncodeJob], out,
+                  t_start: float, t_end: float,
+                  launch: Optional[Tuple[float, float]]) -> None:
+        """Meter one finished launch and resolve its jobs' futures;
+        ``launch`` is its (start, end) on the tracer clock when traced."""
         wall = t_end - t_start
         total_tok = sum(j.n_tokens for j in jobs)
         spt = wall / max(total_tok, 1)
@@ -565,7 +607,6 @@ class EncoderStage:
         done = self.sim_now()
         d = int(self.cfg.d_model)
         tracer = self.obs.tracer
-        tw1 = tracer.now() if tracer.enabled else 0.0
         for i, job in enumerate(jobs):
             emb = out[i, :job.n_items]
             receipt = EncodeReceipt(
@@ -578,13 +619,14 @@ class EncoderStage:
                 padded_len=length,
                 sim_completed=done,
             )
-            if tracer.enabled:
+            if launch is not None:
                 # Receipt values verbatim; the wall window is the shared
-                # launch (tracer clock), the sim window the stage clock.
+                # launch's (tracer readings at its start and end), the sim
+                # window the stage clock.
                 tracer.emit_span(
                     "encode.job", trace_id=job.tag,
                     parent=tracer.root_id(job.tag), track="encoder",
-                    t0=tw1 - wall, t1=tw1,
+                    t0=launch[0], t1=launch[1],
                     sim_t0=done - wall, sim_t1=done,
                     job_id=job.job_id, n_items=job.n_items,
                     n_tokens=job.n_tokens, workload=job.workload,

@@ -25,14 +25,14 @@ from repro.obs.export import (
 )
 from repro.obs.metrics import MetricsRegistry, log_buckets
 from repro.obs.recorder import FlightRecorder
-from repro.obs.trace import NULL_SPAN, Span, TraceContext, Tracer
+from repro.obs.trace import HOST_WORK_SPANS, NULL_SPAN, Span, Tracer
 
 __all__ = [
     "Observability",
     "Tracer",
     "Span",
-    "TraceContext",
     "NULL_SPAN",
+    "HOST_WORK_SPANS",
     "MetricsRegistry",
     "log_buckets",
     "FlightRecorder",

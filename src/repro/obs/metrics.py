@@ -156,13 +156,14 @@ class Gauge(_Family):
 
 
 class _HistogramChild:
-    __slots__ = ("bounds", "counts", "sum", "count", "vmin", "vmax",
-                 "ewma", "_alpha", "_lock")
+    __slots__ = ("bounds", "counts", "_sum", "_comp", "count", "vmin",
+                 "vmax", "ewma", "_alpha", "_lock")
 
     def __init__(self, bounds: Tuple[float, ...], alpha: float):
         self.bounds = bounds
         self.counts = [0] * (len(bounds) + 1)  # +1 overflow bucket
-        self.sum = 0.0
+        self._sum = 0.0
+        self._comp = 0.0  # Neumaier compensation term
         self.count = 0
         self.vmin = math.inf
         self.vmax = -math.inf
@@ -180,13 +181,26 @@ class _HistogramChild:
             i = len(self.bounds)
         with self._lock:
             self.counts[i] += 1
-            self.sum += v
+            # Neumaier-compensated fold, step for step the one Python's
+            # built-in sum() applies to floats: a reader summing the same
+            # observations in the same order gets the same bits.
+            t = self._sum + v
+            if abs(self._sum) >= abs(v):
+                self._comp += (self._sum - t) + v
+            else:
+                self._comp += (v - t) + self._sum
+            self._sum = t
             self.count += 1
             self.vmin = min(self.vmin, v)
             self.vmax = max(self.vmax, v)
             self.ewma = (v if self.count == 1
                          else (1.0 - self._alpha) * self.ewma
                          + self._alpha * v)
+
+    @property
+    def sum(self) -> float:
+        c = self._comp
+        return self._sum + c if c and math.isfinite(c) else self._sum
 
     @property
     def mean(self) -> float:

@@ -26,9 +26,14 @@ Correlation model: the engine opens one **root span per request** keyed by
 Every receipt in the stack already carries ``tag == request_id``, so
 backends emit their per-job spans with ``trace_id=tag`` and
 ``parent=tracer.root_id(tag)`` -- no context object needs to cross the
-submit boundary.  A :class:`TraceContext` (trace id + span id) is still
-threaded through admission tickets and router decisions for layers that
-want an explicit handle.
+submit boundary.
+
+Besides those receipts, each serving thread marks the host work it does
+(:data:`HOST_WORK_SPANS`), the queue a request waits in
+(``request.queued``) and the driver's blocking waits (``engine.idle``), so
+an interval in which the accelerator idles can be named by what the host
+was doing then.  A span's ``track`` names the thread role that ran it
+(``submit``, ``driver``, ``encoder``, ``farm``, ...).
 
 Span records are plain dicts (one per *completed* span -- open spans live
 only in the tracer's open-table), with keys::
@@ -49,18 +54,24 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass
 from typing import Dict, List, Optional
 
-__all__ = ["TraceContext", "Span", "Tracer", "NULL_SPAN"]
+__all__ = ["HOST_WORK_SPANS", "Span", "Tracer", "NULL_SPAN"]
 
-
-@dataclass(frozen=True)
-class TraceContext:
-    """Minimal propagation handle: which request, which enclosing span."""
-
-    trace_id: Optional[int]
-    span_id: Optional[int]
+# The spans that mark a host thread doing named work (as opposed to a
+# request's lifetime markers, the queue, or a blocking wait).  One list for
+# the program, the docs and the benchmark's idle-share readers.
+HOST_WORK_SPANS = (
+    # submit path (the caller's thread)
+    "engine.submit",
+    # the engine's driver thread
+    "engine.barrier", "request.problem", "solve.formulate", "solve.reduce",
+    "engine.resolve", "encoder.tokenize",
+    # the encoder stage's drain thread
+    "encoder.batch", "encoder.pack", "encoder.launch", "encoder.readout",
+    # the COBI farm's drain groups and the host/MCMC pool workers
+    "farm.pack", "farm.place", "farm.readout", "pool.job",
+)
 
 
 class _NullSpan:
@@ -69,7 +80,6 @@ class _NullSpan:
     __slots__ = ()
     trace_id = None
     span_id = None
-    ctx = TraceContext(None, None)
 
     def end(self, sim_t1=None, **attrs) -> None:
         pass
@@ -116,10 +126,6 @@ class Span:
         self.sim_t0 = sim_t0
         self.attrs = attrs
         self._done = False
-
-    @property
-    def ctx(self) -> TraceContext:
-        return TraceContext(self.trace_id, self.span_id)
 
     def set(self, **attrs) -> None:
         """Attach attributes to the span before (or at) end."""

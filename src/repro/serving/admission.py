@@ -62,7 +62,7 @@ from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.farm.packing import estimate_packing, replica_tiers
-from repro.obs import Observability, TraceContext
+from repro.obs import Observability
 
 # Minimum recorded lateness samples before auto_watermark starts widening;
 # below this the quantile is noise.
@@ -135,9 +135,6 @@ class AdmissionTicket:
     backend: Optional[str] = None  # router-chosen backend name (None = default)
     predicted_seconds: float = 0.0  # router-predicted latency incl. queue wait
     sim_at_admit: float = 0.0  # backend sim clock when admitted
-    # Trace propagation: the engine's root-span context rides the ticket so
-    # downstream layers can parent to the request without a side lookup.
-    ctx: Optional[TraceContext] = None
 
 
 @dataclasses.dataclass
@@ -264,7 +261,6 @@ class AdmissionController:
         iterations: int = 1,
         quality_floor: Optional[float] = None,
         extra_seconds: float = 0.0,
-        ctx: Optional[TraceContext] = None,
     ) -> AdmissionTicket:
         """Gate one request carrying ``len(job_lanes)`` planned solve jobs.
 
@@ -282,7 +278,7 @@ class AdmissionController:
         with self._lock:
             depth = len(self._inflight)
             if cfg.max_queue_depth is not None and depth >= cfg.max_queue_depth:
-                self._reject(request_id, "depth", ctx)
+                self._reject(request_id, "depth")
                 raise EngineOverloadedError(
                     f"admission queue full: {depth} requests in flight "
                     f"(max_queue_depth={cfg.max_queue_depth})",
@@ -311,7 +307,7 @@ class AdmissionController:
                     job_lanes, eff_reads, degraded, deadline, sim_now,
                     steps=steps, iterations=iterations, watermark=watermark,
                     quality_floor=quality_floor, depth=depth,
-                    request_id=request_id, ctx=ctx,
+                    request_id=request_id,
                 )
                 backend = decision.backend
                 predicted = decision.predicted_seconds
@@ -332,7 +328,7 @@ class AdmissionController:
                         )
                         degraded = est <= deadline - watermark
                     if est > deadline - watermark:
-                        self._reject(request_id, "deadline", ctx)
+                        self._reject(request_id, "deadline")
                         raise EngineOverloadedError(
                             f"deadline infeasible: estimated completion "
                             f"{est:.6f}s (sim) > deadline {deadline:.6f}s - "
@@ -358,15 +354,14 @@ class AdmissionController:
             if tracer.enabled:
                 tracer.event(
                     "admission.admit", trace_id=request_id,
-                    parent=(ctx.span_id if ctx is not None
-                            else tracer.root_id(request_id)),
+                    parent=tracer.root_id(request_id),
                     track="admission", reads=eff_reads, degraded=degraded,
                     backend=backend, predicted_seconds=predicted,
                     est_completion=est, depth=new_depth)
             return AdmissionTicket(
                 request_id, eff_reads, degraded, est,
                 backend=backend, predicted_seconds=predicted,
-                sim_at_admit=sim_now, ctx=ctx,
+                sim_at_admit=sim_now,
             )
 
     def on_done(self, request_id: int,
@@ -464,21 +459,19 @@ class AdmissionController:
         # historical estimate misses would have fit inside the margin.
         return wm + late[min(len(late) - 1, int(0.9 * len(late)))]
 
-    def _reject(self, request_id: int, reason: str,
-                ctx: Optional[TraceContext]) -> None:
+    def _reject(self, request_id: int, reason: str) -> None:
         """Count (and trace) one shed request."""
         self._m_rejected.labels(reason=reason).inc()
         tracer = self.obs.tracer
         if tracer.enabled:
             tracer.event(
                 "admission.reject", trace_id=request_id,
-                parent=(ctx.span_id if ctx is not None
-                        else tracer.root_id(request_id)),
+                parent=tracer.root_id(request_id),
                 track="admission", reason=reason)
 
     def _route_locked(self, job_lanes, eff_reads, degraded, deadline,
                       sim_now, *, steps, iterations, watermark,
-                      quality_floor, depth, request_id=0, ctx=None):
+                      quality_floor, depth, request_id=0):
         """Router-backed feasibility: per-backend predictions over the work
         already admitted; degrade-retry on infeasibility.  Returns
         ``(RouteDecision, eff_reads, degraded)`` or raises."""
@@ -509,7 +502,7 @@ class AdmissionController:
                     return decision, cfg.reads_floor, True
                 except InfeasibleRoute:
                     pass
-            self._reject(request_id, "deadline", ctx)
+            self._reject(request_id, "deadline")
             raise EngineOverloadedError(
                 f"no routable backend is feasible with {depth} requests in "
                 f"flight: {exc}",

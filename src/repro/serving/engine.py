@@ -114,6 +114,11 @@ from repro.serving.router import BackendRouter, RouterConfig
 from repro.solvers.base import AwaitableFuture, ThreadPoolBackend
 from repro.solvers.cobi import COBI_MAX_SPINS
 
+# With tracing on, the driver's idle wait commits an ``engine.idle`` span at
+# least this often, so a reader of the ring sees a waiting driver's idle
+# time up to about the moment it reads, not only up to its last wake-up.
+IDLE_SPAN_SECONDS = 0.05
+
 # Solvers served through a backend's submit->future loop; the rest (brute /
 # exact / random baselines) run inline in the driver thread via solve_es.
 _POOL_SOLVERS = ("tabu", "sa")
@@ -203,6 +208,12 @@ class _Work:
     # Root trace span, opened when the driver adopts the request (stays
     # NULL_SPAN for queued-cancelled/evicted requests and disabled tracing).
     span: object = NULL_SPAN
+    # Tracer times (tracing on only): when its submit path began (the
+    # ``request.queued`` span's start: from then on the request is in the
+    # system) and when its terminal host work began (the ``engine.resolve``
+    # span's start; 0 = at _resolve).
+    t_queued: float = 0.0
+    t_resolve: float = 0.0
 
 
 class SummarizationEngine:
@@ -428,7 +439,12 @@ class SummarizationEngine:
                 rid = self._next_rid_locked()
         if rid != request.request_id:
             request = dataclasses.replace(request, request_id=rid)
-        return self._enqueue(request, jax.random.fold_in(self._base_key, rid))
+        with self._submit_span(rid) as sp:
+            work = self._admit_work(
+                request, jax.random.fold_in(self._base_key, rid))
+            work.t_queued = sp.t0 if sp else 0.0
+            self._enqueue_works([work])
+        return work.future
 
     def run_batch(self, requests: Sequence, seed: int = 0
                   ) -> List[SelectionResponse]:
@@ -534,6 +550,15 @@ class SummarizationEngine:
 
     # ------------------------------------------------------------ internals
 
+    def _submit_span(self, trace_id: Optional[int]):
+        """The ``engine.submit`` span over one submit path (key, admission,
+        enqueue) on the caller's thread; ``NULL_SPAN`` with tracing off."""
+        tracer = self.obs.tracer
+        if not tracer.enabled:
+            return NULL_SPAN
+        return tracer.span("engine.submit", trace_id=trace_id,
+                           track="submit")
+
     def _enqueue_batch(self, requests: Sequence, seed: int
                        ) -> List[ResponseFuture]:
         """Admit + enqueue a whole batch ATOMICALLY: the driver adopts all of
@@ -555,22 +580,18 @@ class SummarizationEngine:
                     req = dataclasses.replace(req, request_id=rid)
                 resolved.append(req)
         works: List[_Work] = []
-        try:
-            for req in resolved:
-                works.append(
-                    self._admit_work(req, jax.random.fold_in(base, req.request_id))
-                )
-        except BaseException:
-            for work in works:  # released admitted-but-never-queued work
-                self.admission.on_done(work.req.request_id)
-            raise
-        self._enqueue_works(works)
+        with self._submit_span(None) as sp:
+            try:
+                for req in resolved:
+                    works.append(self._admit_work(
+                        req, jax.random.fold_in(base, req.request_id)))
+                    works[-1].t_queued = sp.t0 if sp else 0.0
+            except BaseException:
+                for work in works:  # released admitted-but-never-queued work
+                    self.admission.on_done(work.req.request_id)
+                raise
+            self._enqueue_works(works)
         return [w.future for w in works]
-
-    def _enqueue(self, req, key) -> ResponseFuture:
-        work = self._admit_work(req, key)
-        self._enqueue_works([work])
-        return work.future
 
     def _next_rid_locked(self, taken: Sequence[int] = ()) -> int:
         """Next engine-assigned request id (caller holds ``self._lock``).
@@ -724,10 +745,19 @@ class SummarizationEngine:
         once per round, supply the manual-policy round barrier, resolve
         futures.  Runs until the engine is closed AND no work remains."""
         active: List[tuple] = []  # (generator, work)
+        tracer = self.obs.tracer
         while True:
             with self._new:
                 while not self._queue and not active and not self._closed:
-                    self._new.wait()
+                    if tracer.enabled:
+                        # Emitted whole after each wait, so a waiting
+                        # driver holds no open span.
+                        t_idle = tracer.now()
+                        self._new.wait(IDLE_SPAN_SECONDS)
+                        tracer.emit_span("engine.idle", track="driver",
+                                         t0=t_idle, t1=tracer.now())
+                    else:
+                        self._new.wait()
                 if self._closed and not self._queue and not active:
                     return
                 batch, self._queue = self._queue, []
@@ -757,6 +787,13 @@ class SummarizationEngine:
                 # flush_hint is a no-op; it self-drains).
                 barriers = ([self.backend] if self.router is None
                             else list(self.router.backends.values()))
+                barrier = NULL_SPAN
+                if tracer.enabled and any(be.policy == "manual"
+                                          and be.pending_jobs()
+                                          for be in barriers):
+                    # Only a round that drains work gets a span: the
+                    # self-draining backends' flush_hint is a no-op.
+                    barrier = tracer.span("engine.barrier", track="driver")
                 for be in barriers:
                     try:
                         if be.policy == "manual":
@@ -774,9 +811,13 @@ class SummarizationEngine:
                         # error on their next step.  The driver must outlive
                         # it.
                         traceback.print_exc()
+                barrier.end()
 
     def _resolve(self, work: _Work, response: Optional[SummarizeResponse],
                  error: Optional[BaseException] = None) -> None:
+        tracer = self.obs.tracer
+        if tracer.enabled and not work.t_resolve:
+            work.t_resolve = tracer.now()
         # Realized completion feeds admission's estimate-error tracking, but
         # only on the primary backend's clock -- a pool-served request's
         # sim_completed lives on the pool's wall clock and would poison the
@@ -805,6 +846,11 @@ class SummarizationEngine:
             error.flight_log = tuple(
                 self.obs.recorder.dump(work.req.request_id))
         work.future._finish(response, error)
+        if tracer.enabled:
+            tracer.emit_span(
+                "engine.resolve", trace_id=work.req.request_id,
+                parent=work.span.span_id, track="driver",
+                t0=work.t_resolve, t1=tracer.now())
 
     def _iter_one(self, work: _Work):
         """Generator serving one request; yields once per backend round."""
@@ -826,6 +872,11 @@ class SummarizationEngine:
         )
         tracer.register_root(req.request_id, span)
         work.span = span
+        if tracer.enabled:
+            tracer.emit_span(
+                "request.queued", trace_id=req.request_id,
+                parent=span.span_id, track="engine",
+                t0=work.t_queued, t1=span.t0)
         items = req.items
         m = req.kofn.m
         cfg = self.cfg
@@ -894,11 +945,13 @@ class SummarizationEngine:
             # tests check, so this span carries no meter-named attributes.
             tracer.emit_span(
                 "request.encode", trace_id=req.request_id,
-                parent=span.ctx.span_id, track="engine",
+                parent=span.span_id, track="engine",
                 t0=t_enc_w0, t1=tracer.now(),
                 n_texts=len(texts), staged=self.stage is not None,
             )
-        problem = problem_from_embeddings(req.kofn, items, e)
+        with (span.child("request.problem", track="driver")
+              if tracer.enabled else NULL_SPAN):
+            problem = problem_from_embeddings(req.kofn, items, e)
         if problem.n > COBI_MAX_SPINS and not cfg.decompose:
             cfg = dataclasses.replace(cfg, decompose=True)
         backend_used = None
@@ -964,7 +1017,7 @@ class SummarizationEngine:
             if tracer.enabled:
                 tracer.emit_span(
                     "request.solve", trace_id=req.request_id,
-                    parent=span.ctx.span_id, track="engine",
+                    parent=span.span_id, track="engine",
                     t0=t_solve_w0, t1=tracer.now(),
                     sim_t0=t_serve0,
                     sim_t1=(report.sim_completed
@@ -978,10 +1031,12 @@ class SummarizationEngine:
             if tracer.enabled:
                 tracer.emit_span(
                     "request.solve", trace_id=req.request_id,
-                    parent=span.ctx.span_id, track="engine",
+                    parent=span.span_id, track="engine",
                     t0=t_solve_w0, t1=tracer.now(),
                     solver_invocations=report.solver_invocations,
                 )
+        if tracer.enabled:
+            work.t_resolve = tracer.now()
         hw = self._hardware()
         host_eval = report.solver_invocations * cfg.reads * hw.host_eval_seconds
         metered = report.chip_seconds + report.host_seconds

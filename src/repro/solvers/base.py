@@ -413,13 +413,16 @@ class ThreadPoolBackend:
         submitted = self.sim_now()
 
         def run():
+            tracer = self.obs.tracer
             try:
+                tw0 = tracer.now() if tracer.enabled else 0.0
                 t0 = time.perf_counter()
                 res = self._solve_job(
                     ising, key, reads=reads, steps=steps, check=check,
                     reduce=reduce, **solve_kwargs,
                 )
                 wall = time.perf_counter() - t0
+                tw1 = tracer.now() if tracer.enabled else 0.0
                 done = self.sim_now()
                 with self._lock:
                     self._avg_job_seconds = (
@@ -432,14 +435,14 @@ class ThreadPoolBackend:
                 )
                 self._m_jobs.inc()
                 self._m_secs.observe(wall)
-                tracer = self.obs.tracer
                 if tracer.enabled:
-                    t1 = tracer.now()
+                    # The wall window is the worker call's, read on the
+                    # tracer clock at its start and end.
                     tracer.emit_span(
                         "pool.job", trace_id=tag,
                         parent=tracer.root_id(tag),
                         track=f"pool:{self.solver}",
-                        t0=t1 - wall, t1=t1,
+                        t0=tw0, t1=tw1,
                         sim_t0=submitted, sim_t1=done,
                         job_id=job_id, n=int(ising.n),
                         host_seconds=receipt.host_seconds,

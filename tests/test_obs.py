@@ -118,7 +118,7 @@ def test_root_registration_resolves_until_commit():
     tr = Tracer()
     root = tr.span("request", trace_id=5)
     tr.register_root(5, root)
-    assert tr.root_id(5) == root.ctx.span_id
+    assert tr.root_id(5) == root.span_id
     assert tr.root_id(None) is None
     assert tr.root_id(404) is None
     root.end()
@@ -183,7 +183,7 @@ def test_chrome_trace_roundtrip_and_validation():
     tr = Tracer()
     root = tr.span("request", trace_id=1, track="engine")
     tr.register_root(1, root)
-    tr.emit_span("farm.job", trace_id=1, parent=root.ctx.span_id,
+    tr.emit_span("farm.job", trace_id=1, parent=root.span_id,
                  track="chip0", t0=0.0, t1=0.5, sim_t0=0.0, sim_t1=0.0002)
     tr.event("mark", trace_id=1, track="engine")
     root.end()
