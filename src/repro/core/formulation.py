@@ -24,7 +24,6 @@ Conventions (used consistently across the whole package):
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Optional
 
 import jax
@@ -63,8 +62,8 @@ class EsProblem:
         """Restriction to a subset of sentences (used by decomposition)."""
         idx = np.asarray(idx)
         return EsProblem(
-            mu=jnp.asarray(self.mu)[idx],
-            beta=jnp.asarray(self.beta)[np.ix_(idx, idx)],
+            mu=np.asarray(self.mu)[idx],
+            beta=np.asarray(self.beta)[np.ix_(idx, idx)],
             m=self.m,
             lam=self.lam,
         )
@@ -186,43 +185,24 @@ def qubo_improved(
     """Eq. (10): the improved QUBO with linear bias term ``mu_b``.
 
     ``mu_b=None`` selects the paper's Eq. (12) median-matching rule;
-    ``mu_b=0`` recovers the original formulation Eq. (8).
+    ``mu_b=0`` recovers the original formulation Eq. (8).  Built in host
+    float32 numpy: a request's problem is at most a few thousand entries,
+    and a device program per sentence count cost more than the math.
     """
     if gamma is None:
         gamma = gamma_auto(problem)
-    q = _qubo_improved_q(
-        jnp.asarray(problem.mu, jnp.float32),
-        jnp.asarray(problem.beta, jnp.float32),
-        jnp.float32(problem.lam),
-        jnp.float32(gamma),
-        jnp.float32(0.0 if mu_b is None else mu_b),
-        m=problem.m,
-        use_eq12=mu_b is None,
-    )
+    mu = np.asarray(problem.mu, np.float32)
+    beta = np.asarray(problem.beta, np.float32)
+    lam, gamma = np.float32(problem.lam), np.float32(gamma)
+    if mu_b is None:
+        h, j = _ising_coeffs(mu, beta, problem.m, lam, gamma, np.float32(0.0))
+        off = j[~np.eye(len(h), dtype=bool)]
+        med_j = np.median(off) if off.size else np.float32(0.0)
+        mu_b = 2.0 * (np.median(h) - med_j)
+    lin = -(mu + np.float32(mu_b)) - 2.0 * gamma * problem.m + gamma
+    q = lam * beta + gamma
+    np.fill_diagonal(q, lin)
     return QuboProblem(q=q)
-
-
-@functools.partial(jax.jit, static_argnames=("m", "use_eq12"))
-def _qubo_improved_q(mu, beta, lam, gamma, mu_b, *, m: int, use_eq12: bool) -> Array:
-    """Fused Eq. (10)/(12) build -- one launch per problem size.  Serving
-    builds a QUBO per request, so the eager per-op dispatch added up."""
-    n = mu.shape[-1]
-    if use_eq12:
-        h, j = _ising_coeffs(mu, beta, m, lam, gamma, 0.0)
-        mu_b = 2.0 * (jnp.median(h) - jnp.median(_offdiag_values(j)))
-    lin = -(mu + mu_b) - 2.0 * gamma * m + gamma
-    quad = lam * beta + gamma
-    return quad * (1.0 - jnp.eye(n, dtype=jnp.float32)) + jnp.diag(lin)
-
-
-def _offdiag_values(j: Array) -> Array:
-    # Shape-static strict-off-diagonal extraction (jit-safe): dropping the
-    # last element of the flattened (n, n) matrix and reshaping to
-    # (n-1, n+1) aligns every diagonal entry into column 0.
-    n = j.shape[-1]
-    if n < 2:
-        return jnp.zeros((0,), j.dtype)
-    return jnp.reshape(jnp.ravel(j)[:-1], (n - 1, n + 1))[:, 1:].ravel()
 
 
 # ---------------------------------------------------------------------------
@@ -243,18 +223,11 @@ def qubo_to_ising(qubo: QuboProblem) -> IsingProblem:
     constant, which the tests verify; the improved-formulation phenomenon is
     unchanged.)
     """
-    h, j = _qubo_to_ising_arrays(jnp.asarray(qubo.q, jnp.float32))
-    return IsingProblem(h=h, j=j)
-
-
-@jax.jit
-def _qubo_to_ising_arrays(q: Array):
-    n = q.shape[-1]
-    eye = jnp.eye(n, dtype=jnp.float32)
-    off = q * (1.0 - eye)
-    h = jnp.diag(q) / 2.0 + off.sum(axis=-1) / 2.0
-    j = off / 4.0
-    return h, j
+    q = np.asarray(qubo.q, np.float32)
+    off = q.copy()
+    np.fill_diagonal(off, 0.0)
+    h = np.diag(q) / 2.0 + off.sum(axis=-1) / 2.0
+    return IsingProblem(h=h, j=off / 4.0)
 
 
 def ising_offset(qubo: QuboProblem) -> float:
@@ -267,13 +240,11 @@ def ising_offset(qubo: QuboProblem) -> float:
 
 def _ising_coeffs(mu, beta, m, lam, gamma, mu_b):
     """Closed-form h, J for the (improved) ES Ising model -- used for Eq. 12."""
-    n = mu.shape[-1]
-    eye = jnp.eye(n, dtype=jnp.float32)
-    quad = (lam * beta + gamma) * (1.0 - eye)
+    quad = lam * beta + gamma
+    np.fill_diagonal(quad, 0.0)
     lin = -(mu + mu_b) - 2.0 * gamma * m + gamma
     h = lin / 2.0 + quad.sum(axis=-1) / 2.0
-    j = quad / 4.0
-    return h, j
+    return h, quad / 4.0
 
 
 def original_ising(problem: EsProblem, gamma: Optional[float] = None) -> IsingProblem:

@@ -14,17 +14,16 @@ driver interleaves many requests' rounds, and futures reduce back into a
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import decomposition as decomp
 from repro.core.formulation import (
     EsProblem,
     IsingProblem,
-    es_objective,
     improved_ising,
     original_ising,
 )
@@ -223,14 +222,22 @@ def _best_selection(result) -> np.ndarray:
     return ((spins.astype(np.int32) + 1) // 2).astype(np.int32)
 
 
-def _iteration_keys(key: Array, iterations: int):
-    """Per-iteration (k_quant, k_solve) pairs, split exactly as the
-    sequential loop does so farm and legacy paths stay key-compatible."""
-    out = []
+@functools.partial(jax.jit, static_argnames="iterations")
+def _split_chain(key: Array, *, iterations: int):
+    keys = []
     for _ in range(iterations):
         key, k_quant, k_solve = jax.random.split(key, 3)
-        out.append((k_quant, k_solve))
-    return out
+        keys += [k_quant, k_solve]
+    return tuple(keys)
+
+
+def _iteration_keys(key: Array, iterations: int):
+    """Per-iteration (k_quant, k_solve) pairs, split exactly as the
+    sequential loop does so farm and legacy paths stay key-compatible.
+    One launch returns every key as its own output: no per-element
+    indexing of a key array."""
+    keys = _split_chain(key, iterations=iterations)
+    return list(zip(keys[0::2], keys[1::2]))
 
 
 def _quantized_instance(ising_fp: IsingProblem, cfg: SolveConfig, k_quant: Array):
@@ -318,7 +325,7 @@ def _solve_decomposed(problem: EsProblem, key: Array, cfg: SolveConfig) -> Solve
     )
     if cfg.repair:
         selection = repair_selection(problem, selection)
-    obj = float(es_objective(problem, jnp.asarray(selection)))
+    obj = _objective_np(problem, selection)
     return SolveReport(
         selection, obj, np.asarray([obj]), trace.num_solves * cfg.iterations
     )
@@ -384,10 +391,10 @@ def _submit_iterations(
         check = cfg.int_range is not None or cfg.bits is not None
         keypairs = _iteration_keys(key, cfg.iterations)
         if check:
-            # Same per-iteration keys as the sequential path, one fused
+            # Same per-iteration keys as the sequential path, one draw
             # launch.
             quantized = quantize_ising_many(
-                ising_fp, jnp.stack([kq for kq, _ in keypairs]), cfg.rounding,
+                ising_fp, [kq for kq, _ in keypairs], cfg.rounding,
                 int_range=cfg.int_range or COBI_RANGE, bits=cfg.bits,
             )
             instances = [q.ising for q in quantized]
@@ -663,7 +670,7 @@ def _iter_decomposed_lockstep(
             break
     if cfg.repair:
         selection = repair_selection(problem, selection)
-    obj = float(es_objective(problem, jnp.asarray(selection)))
+    obj = _objective_np(problem, selection)
     return SolveReport(
         selection, obj, np.asarray([obj]), trace.num_solves * cfg.iterations,
         acct.chip_seconds, acct.energy_joules, acct.bytes_h2d, acct.bytes_d2h,
@@ -783,7 +790,7 @@ def _iter_decomposed(
     selection, _trace = plan.final
     if cfg.repair:
         selection = repair_selection(problem, selection)
-    obj = float(es_objective(problem, jnp.asarray(selection)))
+    obj = _objective_np(problem, selection)
     return SolveReport(
         selection, obj, np.asarray([obj]), windows_submitted * cfg.iterations,
         acct.chip_seconds, acct.energy_joules, acct.bytes_h2d, acct.bytes_d2h,

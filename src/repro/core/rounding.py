@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -50,27 +50,69 @@ class QuantizedIsing:
 
 def joint_scale(ising: IsingProblem, int_range: int) -> float:
     """Single scale mapping max(|h|, |J|) onto the integer range."""
-    m = jnp.maximum(jnp.max(jnp.abs(ising.h)), jnp.max(jnp.abs(ising.j)))
-    m = jnp.maximum(m, 1e-12)
-    return float(int_range / m)
+    m = max(np.abs(np.asarray(ising.h)).max(), np.abs(np.asarray(ising.j)).max())
+    return float(int_range / max(m, 1e-12))
 
 
-def _round(v: Array, scheme: str, key: Optional[Array]) -> Array:
+# Smallest draw buckets: every chip-sized instance (n <= 64) shares one
+# draw program per key count.
+DRAW_MIN_H = 64
+DRAW_MIN_J = 64 * 64
+
+
+def _bucket(size: int, floor: int) -> int:
+    b = floor
+    while b < size:
+        b *= 2
+    return b
+
+
+@functools.partial(jax.jit, static_argnames=("bh", "bj"))
+def _uniform_draws(keys, *, bh: int, bj: int):
+    """Each key's rounding uniforms: split into (kh, kj), then flat draws
+    of ``bh`` and ``bj``.  Flat threefry draws are prefix-stable, so
+    ``[:n]`` and ``[:n*n].reshape(n, n)`` are bit for bit the draws of
+    shape ``(n,)`` and ``(n, n)`` -- one program per key count and
+    bucket, not one per instance size."""
+
+    def one(key):
+        kh, kj = jax.random.split(key)
+        return jax.random.uniform(kh, (bh,)), jax.random.uniform(kj, (bj,))
+
+    return jax.vmap(one)(jnp.stack(keys))
+
+
+def _draws(keys, n: int):
+    """(K, n) and (K, n, n) host uniforms for a sequence of K keys."""
+    uh, uj = jax.device_get(_uniform_draws(
+        tuple(keys), bh=_bucket(n, DRAW_MIN_H), bj=_bucket(n * n, DRAW_MIN_J)))
+    return uh[:, :n], uj[:, :n * n].reshape(-1, n, n)
+
+
+def _round(v: np.ndarray, scheme: str, u: Optional[np.ndarray]) -> np.ndarray:
     if scheme == "deterministic":
-        return jnp.round(v)
-    if key is None:
-        raise ValueError(f"scheme {scheme!r} needs a PRNG key")
-    lo = jnp.floor(v)
+        return np.round(v)
+    lo = np.floor(v)
     frac = v - lo
     if scheme == "stochastic_5050":
         # Integer-valued entries stay put; otherwise 50/50 floor vs ceil.
-        p_up = jnp.where(frac > 0.0, 0.5, 0.0)
-    elif scheme == "stochastic":
+        p_up = np.where(frac > 0.0, np.float32(0.5), np.float32(0.0))
+    else:  # "stochastic"
         p_up = frac
-    else:
-        raise ValueError(f"unknown rounding scheme {scheme!r}; want one of {SCHEMES}")
-    up = jax.random.uniform(key, v.shape) < p_up
-    return lo + up.astype(v.dtype)
+    return lo + (u < p_up).astype(v.dtype)
+
+
+def _quantize(h, j, u_h, u_j, *, scheme: str, int_range: int):
+    """Scale + round + mirror in host float32, over any leading dims of the
+    draws: the strict upper triangle of J is rounded once and mirrored."""
+    h = np.asarray(h, np.float32)
+    j = np.asarray(j, np.float32)
+    m = max(np.abs(h).max(), np.abs(j).max())
+    scale = np.float32(int_range) / max(m, np.float32(1e-12))
+    h_q = np.clip(_round(h * scale, scheme, u_h), -int_range, int_range)
+    j_up = np.triu(_round(j * scale, scheme, u_j), k=1)
+    j_q = np.clip(j_up + np.swapaxes(j_up, -1, -2), -int_range, int_range)
+    return h_q, j_q, float(scale)
 
 
 def quantize_ising(
@@ -84,78 +126,43 @@ def quantize_ising(
     """Quantize (h, J) to integers in [-R, R] with the given rounding scheme.
 
     ``bits`` overrides ``int_range`` with the b-bit fixed-point range.
-    Returns integer-valued coefficients and the scale used, so that
-    ``H_int(s) / scale ~= H_fp(s)``.
+    Returns integer-valued coefficients (host float32 arrays) and the scale
+    used, so that ``H_int(s) / scale ~= H_fp(s)``.
     """
-    if bits is not None:
-        int_range = int_range_for_bits(bits)
     if key is None and scheme != "deterministic":
         raise ValueError(f"scheme {scheme!r} needs a PRNG key")
-    if scheme not in SCHEMES:
-        raise ValueError(f"unknown rounding scheme {scheme!r}; want one of {SCHEMES}")
-    if key is None:
-        key = jax.random.key(0)  # unused by the deterministic branch
-    h_q, j_q, scale = _quantize_arrays(
-        jnp.asarray(ising.h, jnp.float32), jnp.asarray(ising.j, jnp.float32), key,
-        scheme=scheme, int_range=int_range,
-    )
-    return QuantizedIsing(ising=IsingProblem(h=h_q, j=j_q), scale=float(scale))
+    return quantize_ising_many(ising, [key], scheme, int_range=int_range,
+                               bits=bits)[0]
 
 
 def quantize_ising_many(
     ising: IsingProblem,
-    keys: Array,
+    keys: Sequence[Array],
     scheme: str = "stochastic",
     *,
     int_range: int = COBI_RANGE,
     bits: Optional[int] = None,
 ) -> list[QuantizedIsing]:
-    """Draw K independent roundings of ONE instance in a single launch.
+    """K independent roundings of ONE instance, one per key, with one draw
+    launch for all K.
 
     The serving pipeline quantizes the same FP Ising once per
-    stochastic-rounding iteration; vmapping over the iteration keys replaces
-    K dispatches with one.  Bit-identical to ``[quantize_ising(ising,
-    scheme, key=k) for k in keys]`` (counter-based PRNG: each row draws its
-    own key's stream); coefficients come back as host numpy arrays.
+    stochastic-rounding iteration.  Bit-identical to ``[quantize_ising(
+    ising, scheme, key=k) for k in keys]``: each row draws its own key's
+    stream.
     """
     if bits is not None:
         int_range = int_range_for_bits(bits)
     if scheme not in SCHEMES:
         raise ValueError(f"unknown rounding scheme {scheme!r}; want one of {SCHEMES}")
-    h_q, j_q, scale = _quantize_arrays_many(
-        jnp.asarray(ising.h, jnp.float32), jnp.asarray(ising.j, jnp.float32), keys,
-        scheme=scheme, int_range=int_range,
-    )
-    h_q, j_q = np.asarray(h_q), np.asarray(j_q)
-    s = float(np.asarray(scale)[0])
+    u_h = u_j = None
+    if scheme != "deterministic":
+        u_h, u_j = _draws(keys, int(np.shape(ising.h)[-1]))
+    h_q, j_q, scale = _quantize(ising.h, ising.j, u_h, u_j, scheme=scheme,
+                                int_range=int_range)
+    if u_h is None:
+        return [QuantizedIsing(ising=IsingProblem(h=h_q, j=j_q), scale=scale)] * len(keys)
     return [
-        QuantizedIsing(ising=IsingProblem(h=h_q[k], j=j_q[k]), scale=s)
-        for k in range(len(h_q))
+        QuantizedIsing(ising=IsingProblem(h=h_q[i], j=j_q[i]), scale=scale)
+        for i in range(len(keys))
     ]
-
-
-@functools.partial(jax.jit, static_argnames=("scheme", "int_range"))
-def _quantize_arrays_many(h: Array, j: Array, keys: Array, *, scheme, int_range):
-    quant = functools.partial(_quantize_arrays, scheme=scheme, int_range=int_range)
-    return jax.vmap(quant, in_axes=(None, None, 0))(h, j, keys)
-
-
-@functools.partial(jax.jit, static_argnames=("scheme", "int_range"))
-def _quantize_arrays(h: Array, j: Array, key: Array, *, scheme: str, int_range: int):
-    """One fused launch per (shape, scheme, range): scale + round + mirror.
-    Serving quantizes every stochastic-rounding iteration of every request,
-    so this is a hot path."""
-    n = h.shape[-1]
-    m = jnp.maximum(jnp.max(jnp.abs(h)), jnp.max(jnp.abs(j)))
-    scale = int_range / jnp.maximum(m, 1e-12)  # == joint_scale(ising, int_range)
-    kh, kj = jax.random.split(key)
-    if scheme == "deterministic":
-        kh = kj = None
-    h_q = jnp.clip(_round(h * scale, scheme, kh), -int_range, int_range)
-    # Round the strict upper triangle once, mirror for symmetry.
-    upper = jnp.triu(jnp.ones((n, n), bool), k=1)
-    j_up = _round(j * scale, scheme, kj)
-    j_q = jnp.where(upper, j_up, 0.0)
-    j_q = j_q + j_q.T
-    j_q = jnp.clip(j_q, -int_range, int_range)
-    return h_q, j_q, scale

@@ -70,23 +70,23 @@ def synthetic_embeddings(
     return e / jnp.linalg.norm(e, axis=-1, keepdims=True)
 
 
-def scores_from_embeddings(e: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def scores_from_embeddings(e) -> Tuple[np.ndarray, np.ndarray]:
     """Paper Eqs. (1)-(2): mu_i = cos(e_i, mean_doc); beta_ij = cos(e_i, e_j).
 
-    Deliberately NOT jit'd: sentence counts vary per request, so a jit cache
-    here would recompile (and grow) per distinct document length for ~8
-    dispatches of savings."""
+    Host float32 numpy on any array-like ``e``: sentence counts vary per
+    request, and device ops -- eager or jitted -- build a program per
+    distinct count."""
     # The eps guard only bites on an exactly-zero row (a sentence fully
     # truncated by the backbone's max_len) -- that row scores mu=0, beta=0
     # instead of NaN-poisoning the whole objective; nonzero rows divide by
     # their exact norm, unchanged.
-    e = e / jnp.maximum(jnp.linalg.norm(e, axis=-1, keepdims=True), 1e-9)
-    doc = jnp.mean(e, axis=0)
-    doc = doc / jnp.maximum(jnp.linalg.norm(doc), 1e-9)
-    mu = e @ doc
+    e = np.asarray(e, np.float32)
+    e = e / np.maximum(np.linalg.norm(e, axis=-1, keepdims=True), np.float32(1e-9))
+    doc = e.mean(axis=0)
+    doc = doc / np.maximum(np.linalg.norm(doc), np.float32(1e-9))
     beta = e @ e.T
-    beta = beta * (1.0 - jnp.eye(e.shape[0]))
-    return mu, beta
+    np.fill_diagonal(beta, 0.0)
+    return e @ doc, beta
 
 
 def synthetic_benchmark(

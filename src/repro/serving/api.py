@@ -26,7 +26,6 @@ from __future__ import annotations
 import dataclasses
 from typing import List, Optional, Sequence
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.formulation import EsProblem
@@ -195,35 +194,37 @@ def problem_from_embeddings(
             f"beta has shape {np.shape(spec.beta)} for {n} items"
         )
     if e is None:
-        mu = jnp.asarray(spec.mu, jnp.float32)
-        beta = jnp.asarray(spec.beta, jnp.float32)
+        mu = np.asarray(spec.mu, np.float32)
+        beta = np.asarray(spec.beta, np.float32)
         return EsProblem(mu=mu, beta=beta, m=spec.m, lam=spec.lam)
     if (spec.relevance == "centroid" and spec.mu is None
             and spec.beta is None):
         mu, beta = scores_from_embeddings(e)
         return EsProblem(mu=mu, beta=beta, m=spec.m, lam=spec.lam)
+    e = np.asarray(e, np.float32)
     e_query = None
     if spec.relevance == "query":
         e_query, e = e[-1], e[:n]
     # General path: mirror scores_from_embeddings' normalization so every
     # relevance source scores against the same unit-norm geometry.
-    e = e / jnp.maximum(jnp.linalg.norm(e, axis=-1, keepdims=True), 1e-9)
+    eps = np.float32(1e-9)
+    e = e / np.maximum(np.linalg.norm(e, axis=-1, keepdims=True), eps)
     if spec.relevance == "centroid":
-        doc = jnp.mean(e, axis=0)
-        doc = doc / jnp.maximum(jnp.linalg.norm(doc), 1e-9)
+        doc = e.mean(axis=0)
+        doc = doc / np.maximum(np.linalg.norm(doc), eps)
         mu = e @ doc
     elif spec.relevance == "query":
-        q = e_query / jnp.maximum(jnp.linalg.norm(e_query), 1e-9)
+        q = e_query / np.maximum(np.linalg.norm(e_query), eps)
         mu = e @ q
     elif spec.relevance == "uniform":
-        mu = jnp.ones((n,), jnp.float32)
+        mu = np.ones((n,), np.float32)
     else:  # "given"
-        mu = jnp.asarray(spec.mu, jnp.float32)
+        mu = np.asarray(spec.mu, np.float32)
     if spec.beta is not None:
-        beta = jnp.asarray(spec.beta, jnp.float32)
+        beta = np.asarray(spec.beta, np.float32)
     else:
         beta = e @ e.T
-        beta = beta * (1.0 - jnp.eye(n))
+        np.fill_diagonal(beta, 0.0)
     return EsProblem(mu=mu, beta=beta, m=spec.m, lam=spec.lam)
 
 
