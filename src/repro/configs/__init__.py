@@ -5,6 +5,7 @@ from repro.configs import (  # noqa: F401
     llama_3_2_vision_11b,
     minitron_8b,
     mixtral_8x22b,
+    moonlight_16b_a3b,
     qwen2_5_32b,
     qwen2_moe_a2_7b,
     sbert_paper,

@@ -33,6 +33,39 @@ class MoEConfig:
     d_ff_shared: int = 0  # 0 -> no shared expert
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # The ``mla_moe`` family's dropless routing (``mlp.moe_dropless``)
+    # scales the normalised weights of its choices by ``routed_scaling``.
+    routed_scaling: float = 1.0
+    # Expert parallelism (``mla_moe``): this device holds
+    # ``experts_held`` of the ``num_experts`` routed experts, the
+    # ``expert_shard``-th contiguous share; the router keeps all of them.
+    experts_held: Optional[int] = None
+    expert_shard: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def first_held(self) -> int:
+        return self.expert_shard * self.held
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaConfig:
+    """DeepSeek-V2/V3 multi-head latent attention (``q_lora_rank`` null):
+    queries straight from the hidden state; keys and values from a
+    normalised low-rank latent, plus one rotary key shared by all heads."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    kv_norm_eps: float = 1e-6  # the latent RMSNorm's own epsilon
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,7 +80,7 @@ class SsmConfig:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # dense | moe | hybrid | ssm | encdec | vlm
+    family: str  # dense | moe | mla_moe | hybrid | ssm | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -62,6 +95,9 @@ class ModelConfig:
     sliding_window: Optional[int] = None  # SWA width (mixtral)
     moe: Optional[MoEConfig] = None
     ssm: Optional[SsmConfig] = None
+    mla: Optional[MlaConfig] = None  # latent attention (family "mla_moe")
+    first_k_dense: int = 0  # leading dense layers ahead of the MoE stack
+    embed_scale: bool = True  # token embedding times sqrt(d_model)
     # Heterogeneous stacks (grouped scan; DESIGN.md sec. 3):
     group_size: int = 1  # layers per scanned super-block
     cross_attn_index: Optional[int] = None  # vlm: local idx of cross-attn layer
@@ -99,8 +135,9 @@ class ModelConfig:
 
     @property
     def n_groups(self) -> int:
-        assert self.n_layers % self.group_size == 0, (self.n_layers, self.group_size)
-        return self.n_layers // self.group_size
+        n = self.n_layers - self.first_k_dense
+        assert n % self.group_size == 0, (n, self.group_size)
+        return n // self.group_size
 
     @property
     def padded_vocab(self) -> int:
@@ -132,7 +169,7 @@ class ModelConfig:
             "remat": False,
         }
         n_groups = min(self.n_groups, 2)
-        scale["n_layers"] = n_groups * self.group_size
+        scale["n_layers"] = n_groups * self.group_size + self.first_k_dense
         if self.moe:
             scale["moe"] = dataclasses.replace(
                 self.moe,
@@ -140,7 +177,13 @@ class ModelConfig:
                 top_k=min(self.moe.top_k, 2),
                 d_ff_expert=64,
                 d_ff_shared=128 if self.moe.d_ff_shared else 0,
+                experts_held=(None if self.moe.experts_held is None
+                              else min(self.moe.experts_held, 4)),
             )
+        if self.mla:
+            scale["mla"] = dataclasses.replace(
+                self.mla, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16)
         if self.ssm:
             scale["ssm"] = dataclasses.replace(self.ssm, d_state=16, head_dim=16, chunk=16)
         if self.encoder_layers:

@@ -25,6 +25,10 @@ stage whose drains run concurrently with Ising drains:
     clock -- the encoder's line on the request bill, next to chip time.
   * ``prewarm()`` sweeps the (batch, length, segment) shape lattice so the
     first open-loop burst hits compiled code, exactly like the farm's.
+  * A routed (``mla_moe``) backbone's launch also returns its per-layer
+    expert counts over the real tokens; the stage meters them in
+    ``encoder_expert_choices_total`` / ``encoder_dropped_choices_total``
+    and an ``encoder.route`` span.
 
 ``encode(texts)`` is the synchronous face (submit + wait), making a stage
 usable anywhere a plain encoder is accepted.  ``submit_query(text)`` is the
@@ -50,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.data.tokenizer import ByteTokenizer
+from repro.data.tokenizer import PAD, ByteTokenizer
 from repro.models import embed_sentences
 from repro.obs import NULL_SPAN, Observability
 from repro.solvers.base import AwaitableFuture
@@ -77,9 +81,33 @@ def _bucket(n: int, base: int) -> int:
 
 @functools.partial(jax.jit, static_argnums=(0, 4))
 def _embed_batch(cfg, params, tokens, segs, n_segments):
-    emb = embed_sentences(cfg, params, tokens, segs, n_segments)
+    """Unit-norm sentence embeddings (B, n_segments, d); a routed
+    (``mla_moe``) backbone returns ``(embeddings, _route_counts(...))``."""
+    routed = cfg.family == "mla_moe"
+    out = embed_sentences(cfg, params, tokens, segs, n_segments,
+                          with_route=routed)
+    emb, route = out if routed else (out, None)
     norm = jnp.linalg.norm(emb, axis=-1, keepdims=True)
-    return emb / jnp.maximum(norm, 1e-9)
+    emb = emb / jnp.maximum(norm, 1e-9)
+    return (emb, _route_counts(cfg, route, tokens != PAD)) if routed else emb
+
+
+def _route_counts(cfg, route, real):
+    """Per MoE layer, over the real tokens ``real`` (B, S): choices of each
+    held expert (``held``, (L, held)), choices of experts held elsewhere
+    (``absent``, (L,)), and held choices not computed (``dropped``, (L,);
+    0 for the dropless layer), all int32."""
+    m = cfg.moe
+    real = real[None, :, :, None]
+    local = route["experts"] - m.first_held  # (L, B, S, k)
+    held = (local >= 0) & (local < m.held)
+    one = jax.nn.one_hot(jnp.where(real & held, local, -1), m.held,
+                         dtype=jnp.int32)
+    axes = (1, 2, 3)
+    return {"held": one.sum(axis=axes),
+            "absent": jnp.sum(real & ~held, axis=axes, dtype=jnp.int32),
+            "dropped": jnp.sum(real & held & ~route["computed"], axis=axes,
+                               dtype=jnp.int32)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -209,6 +237,9 @@ class EncoderStage:
                 "tokens": self._m_tokens.value,
                 "busy": self._m_busy.value,
                 "prewarmed": self._m_prewarmed.value,
+                "choices": {v: self._m_choices.labels(held=v).value
+                            for v in ("true", "false")},
+                "dropped": self._m_dropped.value,
             }
         self.obs = obs
         reg = obs.registry
@@ -224,6 +255,14 @@ class EncoderStage:
             "encoder_busy_seconds_total", "wall seconds inside embed launches")
         self._m_prewarmed = reg.counter(
             "encoder_prewarmed_total", "shapes compiled by prewarm()")
+        # Routed backbones: each real token's expert choices, by whether
+        # this device holds the expert, and held choices not computed.
+        self._m_choices = reg.counter(
+            "encoder_expert_choices_total",
+            "routed expert choices of real tokens", labels=("held",))
+        self._m_dropped = reg.counter(
+            "encoder_dropped_choices_total",
+            "held expert choices of real tokens left uncomputed")
         # Per-workload sec/token: admission reads child.ewma for its encode
         # estimate (multidoc items are systematically longer than selection
         # items, so one global EWMA under-charges them).
@@ -238,6 +277,9 @@ class EncoderStage:
             self._m_tokens.inc(carry["tokens"])
             self._m_busy.inc(carry["busy"])
             self._m_prewarmed.inc(carry["prewarmed"])
+            for v, n in carry["choices"].items():
+                self._m_choices.labels(held=v).inc(n)
+            self._m_dropped.inc(carry["dropped"])
 
     @classmethod
     def tiny(cls, seed: int = 0, **kwargs) -> "EncoderStage":
@@ -463,8 +505,8 @@ class EncoderStage:
                 for g in segments:
                     tokens = jnp.zeros((b, length), jnp.int32)
                     segs = jnp.full((b, length), -1, jnp.int32)
-                    _embed_batch(self.cfg, self.params, tokens, segs,
-                                 int(g)).block_until_ready()
+                    jax.block_until_ready(_embed_batch(
+                        self.cfg, self.params, tokens, segs, int(g)))
                     compiled += 1
         self._m_prewarmed.inc(compiled)
         return compiled
@@ -567,8 +609,11 @@ class EncoderStage:
             t_start = time.monotonic()
             out = _embed_batch(self.cfg, self.params, tokens_d, segs_d,
                                int(g_pad))
-            out.block_until_ready()
+            jax.block_until_ready(out)
             t_end = time.monotonic()
+            counts = None
+            if isinstance(out, tuple):  # a routed backbone's expert counts
+                out, counts = out
             readout = NULL_SPAN
             if traced:
                 tl1 = tracer.now()
@@ -577,10 +622,29 @@ class EncoderStage:
                                  batch_pad=b_pad)
                 tracer.emit_span("encoder.launch", parent=batch.span_id,
                                  track="encoder", t0=tl0, t1=tl1)
+            if counts is not None:
+                with batch.child("encoder.route") as route:
+                    route.set(**self._meter_route(counts))
+            if traced:
                 readout = batch.child("encoder.readout")
             with readout:
                 self._read_out(length, jobs, out, t_start, t_end,
                                (tl0, tl1) if traced else None)
+
+    def _meter_route(self, counts) -> dict:
+        """Count one launch's expert choices into the registry; returns the
+        ``encoder.route`` span's attrs (``expert_max`` and ``expert_mean``
+        over every layer's held experts)."""
+        held = np.asarray(counts["held"])
+        n_held = int(held.sum())
+        absent = int(np.asarray(counts["absent"]).sum())
+        dropped = int(np.asarray(counts["dropped"]).sum())
+        self._m_choices.labels(held="true").inc(n_held)
+        self._m_choices.labels(held="false").inc(absent)
+        self._m_dropped.inc(dropped)
+        return {"held_choices": n_held, "absent_choices": absent,
+                "dropped": dropped, "expert_max": int(held.max()),
+                "expert_mean": float(held.mean())}
 
     def _read_out(self, length: int, jobs: List[_EncodeJob], out,
                   t_start: float, t_end: float,

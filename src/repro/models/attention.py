@@ -1,5 +1,6 @@
 """Attention: GQA / MQA, QKV bias, sliding window, cross-attention, RoPE,
-full and ring-buffer KV caches.  Pure functions; params are dicts.
+full and ring-buffer KV caches, and DeepSeek-style multi-head latent
+attention (encode path).  Pure functions; params are dicts.
 
 Cache protocol (decode): a dict {"k": (B, S_c, KV, HD), "v": ..., "pos":
 (S_c,) int32 absolute position per slot, -1 = empty}.  Full caches have
@@ -14,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.context import constrain
-from repro.models.common import apply_rope, dense_init
+from repro.models.common import apply_rope, dense_init, rmsnorm, rmsnorm_init
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -91,7 +92,7 @@ def _sdpa_chunked(q, k, v, positions, scale, *, causal, window, chunk,
     assert skv % chunk == 0, (skv, chunk)
     nb = skv // chunk
     kb = k.reshape(b, nb, chunk, kvh, d)
-    vb = v.reshape(b, nb, chunk, kvh, d)
+    vb = v.reshape(b, nb, chunk, kvh, v.shape[-1])
     q32 = q.astype(jnp.float32)
     q_pos = positions[:, :, None]  # (B, Sq, 1)
 
@@ -119,7 +120,7 @@ def _sdpa_chunked(q, k, v, positions, scale, *, causal, window, chunk,
 
     m0 = jnp.full((b, h, sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
-    acc0 = jnp.zeros((b, sq, h, d), jnp.float32)
+    acc0 = jnp.zeros((b, sq, h, v.shape[-1]), jnp.float32)
     k_pos_b = positions[:, :skv].reshape(b, nb, chunk) if positions.shape[1] == skv \
         else jnp.broadcast_to(jnp.arange(skv)[None], (b, skv)).reshape(b, nb, chunk)
     (m, l, acc), _ = jax.lax.scan(
@@ -128,6 +129,40 @@ def _sdpa_chunked(q, k, v, positions, scale, *, causal, window, chunk,
     )
     denom = jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
     return (acc / denom).astype(q.dtype)
+
+
+def _attend(cfg, q, k, v, positions, scale, *, mode, causal):
+    """Attention over the whole sequence (train / prefill / encode)."""
+    b, s = q.shape[:2]
+    w = cfg.sliding_window
+    probs_dtype = jnp.bfloat16 if getattr(cfg, "attn_probs_bf16", False) else None
+    chunk = getattr(cfg, "attn_chunk", None)
+    impl = getattr(cfg, "attn_impl", "auto")
+    # Pallas flash kernel: train-mode only (the kernel derives positions
+    # from block indices, which matches the contiguous arange positions
+    # of train/encode calls but not a prefill continuation), and the
+    # sequence must tile into the kernel's q/kv blocks.  The encoder
+    # stage's power-of-two length buckets satisfy both by construction.
+    if (impl == "flash" and mode == "train"
+            and s % min(128, s) == 0 and q.shape[-1] <= 128
+            and v.shape[-1] == q.shape[-1]):
+        from repro.kernels.flash_attention import flash_attention
+
+        return flash_attention(
+            q, k, v, causal=causal, window=w,
+            interpret=jax.default_backend() != "tpu",
+        )
+    if impl != "sdpa" and chunk and s % chunk == 0 and s > chunk:
+        return _sdpa_chunked(
+            q, k, v, positions, scale, causal=causal, window=w, chunk=chunk,
+            probs_dtype=probs_dtype,
+        )
+    q_pos = positions[:, :, None]  # (B, S, 1)
+    k_pos = positions[:, None, :]  # (B, 1, S)
+    mask = k_pos <= q_pos if causal else jnp.ones((b, s, s), bool)
+    if w is not None and causal:
+        mask &= k_pos > q_pos - w
+    return _sdpa(q, k, v, mask, scale, probs_dtype=probs_dtype)
 
 
 def self_attention(
@@ -148,35 +183,9 @@ def self_attention(
     w = cfg.sliding_window
 
     probs_dtype = jnp.bfloat16 if getattr(cfg, "attn_probs_bf16", False) else None
-    chunk = getattr(cfg, "attn_chunk", None)
-    impl = getattr(cfg, "attn_impl", "auto")
 
     if mode in ("train", "prefill"):
-        # Pallas flash kernel: train-mode only (the kernel derives positions
-        # from block indices, which matches the contiguous arange positions
-        # of train/encode calls but not a prefill continuation), and the
-        # sequence must tile into the kernel's q/kv blocks.  The encoder
-        # stage's power-of-two length buckets satisfy both by construction.
-        if (impl == "flash" and mode == "train"
-                and s % min(128, s) == 0 and q.shape[-1] <= 128):
-            from repro.kernels.flash_attention import flash_attention
-
-            out = flash_attention(
-                q, k, v, causal=causal, window=w,
-                interpret=jax.default_backend() != "tpu",
-            )
-        elif (impl != "sdpa" and chunk and s % chunk == 0 and s > chunk):
-            out = _sdpa_chunked(
-                q, k, v, positions, scale, causal=causal, window=w, chunk=chunk,
-                probs_dtype=probs_dtype,
-            )
-        else:
-            q_pos = positions[:, :, None]  # (B, S, 1)
-            k_pos = positions[:, None, :]  # (B, 1, S)
-            mask = k_pos <= q_pos if causal else jnp.ones((b, s, s), bool)
-            if w is not None and causal:
-                mask &= k_pos > q_pos - w
-            out = _sdpa(q, k, v, mask, scale, probs_dtype=probs_dtype)
+        out = _attend(cfg, q, k, v, positions, scale, mode=mode, causal=causal)
         new_cache = None
         if mode == "prefill" and cache is not None:
             s_c = cache["k"].shape[1]
@@ -238,3 +247,52 @@ def cross_attention(
     if gated and "gate" in p:
         out = jnp.tanh(p["gate"]).astype(out.dtype) * out
     return out
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3, q_lora_rank null)
+# ---------------------------------------------------------------------------
+
+
+def mla_init(key, cfg):
+    m = cfg.mla
+    d, h = cfg.d_model, cfg.n_heads
+    rank, nope, rope, vd = (m.kv_lora_rank, m.qk_nope_head_dim,
+                            m.qk_rope_head_dim, m.v_head_dim)
+    ks = jax.random.split(key, 4)
+    dt = cfg.dtype
+    return {
+        "wq": dense_init(ks[0], (d, h * (nope + rope)), dt),
+        "wkv_a": dense_init(ks[1], (d, rank + rope), dt),
+        "kv_norm": rmsnorm_init(rank, dt),
+        "wkv_b": dense_init(ks[2], (rank, h * (nope + vd)), dt),
+        "wo": dense_init(ks[3], (h * vd, d), dt, scale=(h * vd) ** -0.5),
+    }
+
+
+def mla_attention(p, cfg, x: Array, positions: Array) -> Array:
+    """Causal latent attention over the whole sequence (the encode path; no
+    KV cache).  Per head, the query is ``[q_nope, rope(q_pe)]`` and the key
+    ``[k_nope, rope(k_pe)]``, where ``k_nope`` and the value come from the
+    RMS-normalised latent and the one rotary key ``k_pe`` is shared by
+    every head; the softmax scale is the query width's inverse square root
+    (Moonlight-16B-A3B: latent 512, query 128 + 64, value 128).  Rotary
+    channels are half-split (``apply_rope``), a fixed permutation of the
+    published interleaved layout's ``wq`` / ``wkv_a`` columns."""
+    m = cfg.mla
+    b, s, _ = x.shape
+    h = cfg.n_heads
+    rank, nope, rope, vd = (m.kv_lora_rank, m.qk_nope_head_dim,
+                            m.qk_rope_head_dim, m.v_head_dim)
+    q = (x @ p["wq"]).reshape(b, s, h, nope + rope)
+    kv_a = x @ p["wkv_a"]
+    latent = rmsnorm(p["kv_norm"], kv_a[..., :rank], eps=m.kv_norm_eps)
+    kv = (latent @ p["wkv_b"]).reshape(b, s, h, nope + vd)
+    q_pe = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    k_pe = apply_rope(kv_a[..., None, rank:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, h, rope))], axis=-1)
+    out = _attend(cfg, q, k, kv[..., nope:], positions, (nope + rope) ** -0.5,
+                  mode="train", causal=True)
+    return out.reshape(b, s, h * vd) @ p["wo"]

@@ -1,5 +1,7 @@
-"""MLPs (SwiGLU / GeGLU / plain) and Mixture-of-Experts with GShard-style
-capacity dispatch (shardable one-hot einsums; see DESIGN.md sec. 5)."""
+"""MLPs (SwiGLU / GeGLU / plain) and Mixture-of-Experts: GShard-style
+capacity dispatch (shardable one-hot einsums; see DESIGN.md sec. 5), and
+DeepSeek-V3's dropless sigmoid routing over a device's share of the
+experts (``moe_dropless``)."""
 
 from __future__ import annotations
 
@@ -53,7 +55,7 @@ def moe_init(key, cfg):
 
     def expert_leaf(k, shape, scale=None):
         return jax.vmap(lambda kk: dense_init(kk, shape, dt, scale))(
-            jax.random.split(k, e)
+            jax.random.split(k, m.held)
         )
 
     p = {
@@ -64,6 +66,10 @@ def moe_init(key, cfg):
     }
     if m.d_ff_shared:
         p["shared"] = mlp_init(ks[4], cfg, d_ff=m.d_ff_shared)
+    if cfg.family == "mla_moe":
+        # score-correction bias (trained for load balance; random here)
+        p["router_bias"] = 0.1 * jax.random.normal(ks[5], (e,), jnp.float32)
+    elif m.d_ff_shared:
         p["shared_gate"] = dense_init(ks[5], (d, 1), dt)
     return p
 
@@ -144,3 +150,76 @@ def moe_apply(p, cfg, x: Array) -> Tuple[Array, Array]:
         sg = jax.nn.sigmoid(x @ p["shared_gate"]).astype(x.dtype)
         y = y + sg * mlp_apply(p["shared"], cfg, x)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless sigmoid routing over this device's share of the experts
+# ---------------------------------------------------------------------------
+
+
+def route_sigmoid(p, cfg, x: Array) -> Tuple[Array, Array]:
+    """DeepSeek-V3 router (scoring_func sigmoid, topk_method noaux_tc, one
+    group): x (T, D) -> (weights (T, k) float32, experts (T, k) int32).
+
+    Scores are float32 sigmoids of float32 logits (full-precision matmul);
+    the correction bias steers the top-k choice only, and the chosen
+    unbiased scores are normalised to sum 1 and scaled."""
+    m = cfg.moe
+    logits = jnp.dot(x.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + p["router_bias"], m.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return w * m.routed_scaling, idx
+
+
+def moe_dropless(p, cfg, x: Array) -> Tuple[Array, dict]:
+    """x: (B, S, D) -> (out, route).
+
+    Routes every token over all ``num_experts`` and computes, for every
+    choice that lands on one of this device's ``held`` experts, that
+    expert's SwiGLU with no capacity: choices are sorted by held expert,
+    the held ones fill the front of a buffer sized for the worst case (all
+    of a token's choices held), and three ``ragged_dot`` grouped matmuls
+    run over the groups.  Each token's result is the float32 sum of its
+    own choices' weighted outputs in choice order, so it never depends on
+    batch-mates or padding.  Choices for absent experts contribute nothing
+    here (their devices would add them).  The shared experts add in
+    ungated.  ``route`` holds each token's choices (``experts``, (B, S,
+    k) int32) and whether each was computed here (``computed``: held and
+    inside the buffer, which every held choice is by construction)."""
+    m = cfg.moe
+    b, s, d = x.shape
+    t, k, held = b * s, m.top_k, m.held
+    xf = x.reshape(t, d)
+    w, idx = route_sigmoid(p, cfg, xf)
+    local = idx - m.first_held
+    is_held = (local >= 0) & (local < held)
+    group = jnp.where(is_held, local, held).reshape(-1)  # absent last
+    order = jnp.argsort(group, stable=True)  # (T*k,) choice slots
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    n_held = sizes.sum()
+    rows = t * min(k, held)  # most held choices T tokens can make
+    slot = order[:rows]
+    xs = xf[slot // k]
+    act = activation(cfg.act)
+    hid = act(jax.lax.ragged_dot(xs, p["w_gate"], sizes)) * \
+        jax.lax.ragged_dot(xs, p["w_in"], sizes)
+    ys = jax.lax.ragged_dot(hid, p["w_out"], sizes)
+    ys = jnp.where((jnp.arange(rows) < n_held)[:, None], ys, 0)
+    # back to (token, choice): each held slot's row in the sorted buffer
+    pos = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    pos = pos.reshape(t, k)
+    take = is_held & (pos < rows)
+    pos = jnp.minimum(pos, rows - 1)
+    y = jnp.zeros((t, d), jnp.float32)
+    for c in range(k):
+        yc = ys[pos[:, c]].astype(jnp.float32) * w[:, c, None]
+        y = y + jnp.where(take[:, c, None], yc, 0.0)
+    y = y.astype(x.dtype).reshape(b, s, d)
+    if "shared" in p:
+        y = y + mlp_apply(p["shared"], cfg, x)
+    return y, {"experts": idx.reshape(b, s, k).astype(jnp.int32),
+               "computed": take.reshape(b, s, k)}

@@ -86,6 +86,49 @@ def _attn_block_apply(p, cfg, x, positions, *, mode, cache, memory=None, cross=F
     return x + m, new_cache, aux
 
 
+def _mla_block_init(key, cfg, *, dense: bool):
+    """One DeepSeek-V3 decoder block: latent attention, then a dense SwiGLU
+    (the leading layers) or the dropless routed experts."""
+    k1, k2 = jax.random.split(key)
+    p = {
+        "attn_norm": rmsnorm_init(cfg.d_model, cfg.dtype),
+        "attn": attn.mla_init(k1, cfg),
+        "mlp_norm": rmsnorm_init(cfg.d_model, cfg.dtype),
+    }
+    if dense:
+        p["mlp"] = mlp_mod.mlp_init(k2, cfg)
+    else:
+        p["moe"] = mlp_mod.moe_init(k2, cfg)
+    return p
+
+
+def _mla_block_apply(p, cfg, x, positions):
+    """-> (x, route); route is None for a dense block."""
+    h = rmsnorm(p["attn_norm"], x, eps=cfg.norm_eps)
+    x = x + attn.mla_attention(p["attn"], cfg, h, positions)
+    h = rmsnorm(p["mlp_norm"], x, eps=cfg.norm_eps)
+    if "moe" in p:
+        m, route = mlp_mod.moe_dropless(p["moe"], cfg, h)
+        return x + m, route
+    return x + mlp_mod.mlp_apply(p["mlp"], cfg, h), None
+
+
+def _mla_moe_stack(cfg, params, x, positions):
+    """Leading dense blocks, then the scanned MoE blocks -> (x, route) with
+    route's leaves stacked over the MoE layers."""
+    def dense_fn(x, p):
+        return _mla_block_apply(p, cfg, x, positions)[0], None
+
+    def moe_fn(x, p):
+        return _mla_block_apply(p, cfg, x, positions)
+
+    if cfg.remat:
+        dense_fn, moe_fn = jax.checkpoint(dense_fn), jax.checkpoint(moe_fn)
+    if "dense" in params:
+        x, _ = jax.lax.scan(dense_fn, x, params["dense"])
+    return jax.lax.scan(moe_fn, x, params["layers"])
+
+
 def _mamba_block_init(key, cfg):
     return {
         "norm": rmsnorm_init(cfg.d_model, cfg.dtype),
@@ -138,6 +181,8 @@ def _group_init(key, cfg):
         return _attn_block_init(
             key, cfg, use_moe=cfg.moe is not None, with_cross=(fam == "encdec")
         )
+    if fam == "mla_moe":
+        return _mla_block_init(key, cfg, dense=False)
     if fam == "vlm":
         k1, k2 = jax.random.split(key)
         n_self = g - 1
@@ -312,6 +357,10 @@ def init_params(cfg, key) -> dict:
         params["shared"] = {"attn": _attn_block_init(ks[3], cfg)}
     if cfg.family == "encdec":
         params["encoder"] = _encoder_init(ks[4], cfg)
+    if cfg.first_k_dense:
+        params["dense"] = stack_layer_params(
+            lambda k: _mla_block_init(k, cfg, dense=True), ks[5],
+            cfg.first_k_dense)
     return params
 
 
@@ -338,11 +387,29 @@ def forward(
     frontend: Optional[Array] = None,  # (B, T, d) vlm/audio stub embeddings
     return_hidden: bool = False,
 ) -> Tuple[Array, Optional[dict], Array]:
+    x, new_cache, aux, _ = _backbone(cfg, params, tokens, positions, mode=mode,
+                                     cache=cache, frontend=frontend)
+    if return_hidden:
+        return x, new_cache, aux
+    return _logits(cfg, params, x), new_cache, aux
+
+
+def _backbone(cfg, params, tokens, positions, *, mode, cache, frontend):
+    """Hidden states before the final norm -> (x, new_cache, aux, route);
+    ``route`` is the MoE routing record of an ``mla_moe`` stack, else None."""
     b, s = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x = params["embed"][tokens] * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
+    x = params["embed"][tokens]
+    if cfg.embed_scale:
+        x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
     x = constrain(x, "batch", None, None)
+    if cfg.family == "mla_moe":
+        if mode != "train":
+            raise NotImplementedError(
+                "mla_moe serves the encode path only (no latent KV cache)")
+        x, route = _mla_moe_stack(cfg, params, x, positions)
+        return x, None, jnp.zeros((), jnp.float32), route
 
     memory = None
     if cfg.family in ("vlm", "encdec"):
@@ -380,10 +447,7 @@ def forward(
         new_cache = {"layers": new_layer_cache}
         if memory is not None:
             new_cache["memory"] = memory
-
-    if return_hidden:
-        return x, new_cache, aux
-    return _logits(cfg, params, x), new_cache, aux
+    return x, new_cache, aux, None
 
 
 def _dummy_cache(cfg, batch, seq):
@@ -428,18 +492,20 @@ def decode_step(cfg, params, tokens, positions, cache):
 
 
 def embed_sentences(cfg, params, tokens: Array, seg_ids: Array, n_segments: int,
-                    *, frontend=None) -> Array:
+                    *, frontend=None, with_route: bool = False):
     """Mean-pool hidden states per sentence segment -> (B, n_segments, d).
 
     This is the bridge from any backbone to the paper's mu/beta scores
     (DESIGN.md: the technique is a post-encoder combinatorial head).
     seg_ids: (B, S) int32 sentence id per token, -1 for padding.
+    ``with_route=True`` returns ``(embeddings, route)``, route being the
+    MoE routing record of an ``mla_moe`` backbone (None otherwise).
     """
-    hidden, _, _ = forward(
-        cfg, params, tokens, mode="train", frontend=frontend, return_hidden=True
-    )
+    hidden, _, _, route = _backbone(cfg, params, tokens, None, mode="train",
+                                    cache=None, frontend=frontend)
     b, s, d = hidden.shape
     onehot = jax.nn.one_hot(seg_ids, n_segments, dtype=jnp.float32)  # (B,S,G)
     sums = jnp.einsum("bsd,bsg->bgd", hidden.astype(jnp.float32), onehot)
     counts = jnp.maximum(onehot.sum(axis=1), 1.0)  # (B,G)
-    return sums / counts[..., None]
+    emb = sums / counts[..., None]
+    return (emb, route) if with_route else emb
