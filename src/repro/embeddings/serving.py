@@ -23,8 +23,9 @@ stage whose drains run concurrently with Ising drains:
   * Each job's :class:`EncodeReceipt` meters encoder wall seconds (launch
     wall time attributed by token share), h2d/d2h bytes, and the stage
     clock -- the encoder's line on the request bill, next to chip time.
-  * ``prewarm()`` sweeps the (batch, length, segment) shape lattice so the
-    first open-loop burst hits compiled code, exactly like the farm's.
+  * ``prewarm()`` compiles the (batch, length, segment) shape lattice
+    ahead of time, launching none of it, so the first open-loop burst hits
+    compiled code, as the farm's does.
   * A routed (``mla_moe``) backbone's launch also returns its per-layer
     expert counts over the real tokens; the stage meters them in
     ``encoder_expert_choices_total`` / ``encoder_dropped_choices_total``
@@ -492,7 +493,11 @@ class EncoderStage:
                 segments: Sequence[int] = (SEG_BUCKET,)) -> int:
         """Compile the (batch, length, segments) shape lattice up front so
         the first open-loop burst hits compiled code (the farm's
-        ``prewarm()`` idiom one stage earlier).  Returns shapes compiled."""
+        ``prewarm()`` idiom one stage earlier).  Nothing is launched: each
+        shape is lowered and compiled on zero arrays of the shapes and
+        types a drain passes, so the executable lands in the cache
+        ``_run_group``'s call reads.
+        Returns shapes compiled."""
         if lengths is None:
             lengths = []
             length = MIN_LEN_BUCKET
@@ -505,8 +510,8 @@ class EncoderStage:
                 for g in segments:
                     tokens = jnp.zeros((b, length), jnp.int32)
                     segs = jnp.full((b, length), -1, jnp.int32)
-                    jax.block_until_ready(_embed_batch(
-                        self.cfg, self.params, tokens, segs, int(g)))
+                    _embed_batch.lower(self.cfg, self.params, tokens, segs,
+                                       int(g)).compile()
                     compiled += 1
         self._m_prewarmed.inc(compiled)
         return compiled

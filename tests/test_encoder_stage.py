@@ -17,6 +17,7 @@ import pytest
 from repro.core import SolveConfig
 from repro.data.synthetic import scores_from_embeddings, synthetic_document
 from repro.embeddings import EncoderStage
+from repro.embeddings import serving
 from repro.farm import CobiFarm
 from repro.serving import SummarizationEngine
 
@@ -83,6 +84,68 @@ def test_receipts_and_stats_meter_the_stage():
     stage.close()
     with pytest.raises(RuntimeError, match="closed"):
         stage.submit(["x."])
+
+
+# ------------------------------------------------- prewarm compiles only
+
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+class _LowerOnly:
+    """Stands in for the jitted ``_embed_batch``: lowering goes through,
+    a launch fails the test."""
+
+    def __init__(self, fn):
+        self.fn = fn
+
+    def lower(self, *args, **kwargs):
+        return self.fn.lower(*args, **kwargs)
+
+    def __call__(self, *args, **kwargs):
+        raise AssertionError("prewarm launched _embed_batch")
+
+
+def _routed_stage():
+    from repro.configs import get_config
+    from repro.models import init_params
+
+    cfg = get_config("moonlight-16b-a3b").reduced()
+    params = jax.jit(init_params, static_argnums=0)(cfg, jax.random.key(2))
+    return EncoderStage(cfg, params, max_len=128)
+
+
+@pytest.mark.parametrize("make_stage", [EncoderStage.tiny, _routed_stage],
+                         ids=["sbert-tiny", "routed-small"])
+def test_prewarm_compiles_without_launching(make_stage, monkeypatch):
+    """``prewarm`` builds each shape's program and launches none; a
+    following submit of a warmed shape builds no ``_embed_batch``."""
+    stage = make_stage()
+    jax.clear_caches()  # shapes other tests compiled would hide the build
+    lengths = [64, 128]
+    built = []
+
+    def on_build(event, duration, **kw):
+        if event == _COMPILE:
+            built.append(kw.get("fun_name", "?"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_build)
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(serving, "_embed_batch", _LowerOnly(serving._embed_batch))
+            assert stage.prewarm(lengths=lengths, batches=[4],
+                                 segments=[8]) == 2
+        assert stage.stats().launches == 0
+        assert stage.stats().prewarmed == 2
+        assert built.count("jit(_embed_batch)") == 2
+        del built[:]
+        fut = stage.submit(synthetic_document(5, 2))
+        fut.result(timeout=120)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_build)
+        stage.close()
+    assert fut.receipt().padded_len in lengths
+    assert stage.stats().launches == 1
+    assert "jit(_embed_batch)" not in built
 
 
 # ------------------------------------------------- flash-attention parity
